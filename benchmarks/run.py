@@ -24,8 +24,8 @@ Full-scale variants: bench_logic_rl --full, repro.launch.dryrun --all.
 ``--smoke``: seconds-scale pass (reduced simulator workloads, no jit-heavy
 roofline or real-RL sections) — the default verification path; full runs
 are opt-in.  The smoke pass sweeps every registered scheduling policy by
-name and runs examples/quickstart.py end to end, so a registry entry (or
-the quickstart) that rots fails the smoke gate.
+name and runs examples/quickstart.py end to end (in this process), so a
+registry entry (or the quickstart) that rots fails the smoke gate.
 
 ``--json PATH``: additionally write the rows as structured JSON
 ({name, us_per_call, derived} plus the git sha) — the artifact the CI
@@ -34,29 +34,31 @@ smoke gate diffs against the checked-in ``BENCH_smoke.json`` baseline
 """
 from __future__ import annotations
 
+import contextlib
+import io
 import json
 import os
+import runpy
 import subprocess
 import sys
 import time
 
+from repro.compile_cache import enable_compile_cache
+
 
 def quickstart_smoke_row() -> str:
-    """Run examples/quickstart.py in a subprocess as a smoke check."""
+    """Run examples/quickstart.py's main() in this process (one process
+    holds the chip) and check its captured output."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    quickstart = runpy.run_path(os.path.join(root, "examples",
+                                             "quickstart.py"))
+    out = io.StringIO()
     t0 = time.time()
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "examples", "quickstart.py")],
-        capture_output=True, text=True, env=env, timeout=600)
+    with contextlib.redirect_stdout(out):
+        quickstart["main"]()
     dt = time.time() - t0
-    ok = (proc.returncode == 0
-          and "micro-curriculum batch means:" in proc.stdout)
-    if not ok:
-        print(proc.stdout, file=sys.stderr)
-        print(proc.stderr, file=sys.stderr)
+    if "micro-curriculum batch means:" not in out.getvalue():
+        print(out.getvalue(), file=sys.stderr)
         raise RuntimeError("examples/quickstart.py smoke check failed")
     return f"smoke/quickstart,{dt*1e6:.0f},ok=1"
 
@@ -100,6 +102,7 @@ def json_path_from_argv(argv) -> str:
 
 
 def main() -> None:
+    enable_compile_cache()
     from benchmarks import (bench_ablation, bench_autoscale, bench_breakdown,
                             bench_logic_rl, bench_overlap, bench_prefix_share,
                             bench_replicas, bench_serving, bench_throughput,

@@ -16,6 +16,7 @@ the same thing declaratively.
 """
 import random
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.buffer import Mode, StatefulRolloutBuffer
 from repro.core.orchestrator import RolloutOrchestrator, SortedRLConfig
 from repro.core.policy import make_policy
@@ -72,4 +73,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

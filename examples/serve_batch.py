@@ -9,6 +9,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.buffer import BufferEntry
 from repro.data import logic
 from repro.models.model import build_model
@@ -55,4 +56,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

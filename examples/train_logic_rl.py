@@ -8,6 +8,7 @@ built by the one-call session builder.
 import argparse
 import json
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.buffer import Mode
 from repro.core.policy import available_policies
 from repro.rl.session import RLSession, SessionConfig
@@ -44,4 +45,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

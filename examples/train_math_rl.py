@@ -6,6 +6,7 @@ builder — the only difference from the logic driver is ``task="math"``.
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.buffer import Mode
 from repro.core.policy import available_policies
 from repro.rl.session import RLSession, SessionConfig
@@ -35,4 +36,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

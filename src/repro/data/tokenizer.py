@@ -10,6 +10,7 @@ from typing import Dict, List, Sequence
 
 PAD, BOS, EOS, SEP, ANS, THINK = "<pad>", "<bos>", "<eos>", "<sep>", "<ans>", "<think>"
 SPECIALS = [PAD, BOS, EOS, SEP, ANS, THINK]
+UNK = "<unk>"          # decode-only: never an id of a task vocabulary
 
 
 class Vocab:
@@ -38,4 +39,7 @@ class Vocab:
         return [self.stoi[w] for w in words]
 
     def decode(self, ids: Sequence[int]) -> List[str]:
-        return [self.itos[int(i)] for i in ids]
+        """Ids past this vocabulary (a model with a wider vocab generated
+        them) decode to ``UNK``, which no verifier accepts."""
+        n = len(self.itos)
+        return [self.itos[i] if 0 <= i < n else UNK for i in map(int, ids)]

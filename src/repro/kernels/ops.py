@@ -1,14 +1,13 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``use_pallas(True)`` switches the model layers onto the kernels (TPU); the
-default pure-jnp path is used on CPU and as the oracle.  ``interpret``
-defaults to True because this container is CPU-only — on real TPU set
-``REPRO_PALLAS_INTERPRET=0``.
+Each kernel runs compiled on a TPU and in Pallas interpret mode when
+JAX's default backend is the CPU (:func:`repro.kernels.default_interpret`,
+resolved at trace time).  The model layers call the pure-jnp paths; the
+tests compare these wrappers with the oracles in ``ref.py``.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -20,21 +19,19 @@ from repro.kernels.ragged_decode_attention import (
 from repro.kernels.ragged_decode_attention import (
     ragged_decode_attention as _ragged)
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
 
 @functools.partial(jax.jit, static_argnames=("block_k", "softcap"))
 def ragged_decode_attention(q, k_cache, v_cache, kv_len, block_k: int = 128,
                             softcap: float = 0.0):
     return _ragged(q, k_cache, v_cache, kv_len, block_k=block_k,
-                   softcap=softcap, interpret=INTERPRET)
+                   softcap=softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap",))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len,
                            softcap: float = 0.0):
     return _paged(q, k_pages, v_pages, block_tables, kv_len,
-                  softcap=softcap, interpret=INTERPRET)
+                  softcap=softcap)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap",))
@@ -42,16 +39,14 @@ def paged_decode_attention_int8(q, k_pages, v_pages, k_scales, v_scales,
                                 block_tables, kv_len, softcap: float = 0.0):
     """Paged decode over int8-quantized pages with per-page f32 scales."""
     return _paged(q, k_pages, v_pages, block_tables, kv_len,
-                  softcap=softcap, k_scales=k_scales, v_scales=v_scales,
-                  interpret=INTERPRET)
+                  softcap=softcap, k_scales=k_scales, v_scales=v_scales)
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "block_v", "softcap"))
-def fused_sample(x, w, top_k: int = 1, block_v: int = 128,
-                 softcap: float = 0.0):
-    """Fused LM-head matmul + top-k + logsumexp (no (B, V) round-trip)."""
-    return _fused_sample(x, w, top_k=top_k, block_v=block_v,
-                         softcap=softcap, interpret=INTERPRET)
+@functools.partial(jax.jit, static_argnames=("block_v", "softcap"))
+def fused_sample(x, w, block_v: int = 128, softcap: float = 0.0):
+    """Fused LM-head matmul + greedy token + logsumexp (no (B, V)
+    round-trip)."""
+    return _fused_sample(x, w, block_v=block_v, softcap=softcap)
 
 
 @functools.partial(jax.jit,
@@ -61,5 +56,4 @@ def flash_attention(q, k, v, seg_ids=None, block_q: int = 128,
                     block_k: int = 128, window: int = 0,
                     softcap: float = 0.0):
     return _flash(q, k, v, seg_ids=seg_ids, block_q=block_q,
-                  block_k=block_k, window=window, softcap=softcap,
-                  interpret=INTERPRET)
+                  block_k=block_k, window=window, softcap=softcap)

@@ -21,20 +21,22 @@ Two variants share one kernel body:
 Tiling: grid (B, S // block_k); each program holds the full (H, D) query
 tile in VMEM plus one (block_k, Kh, D) cache tile; flash-decode online
 softmax accumulates in VMEM scratch across the sequential k dimension.
-MXU alignment: block_k multiples of 128; D is the lane dimension.  For
-the paged variant block_k == page size; production pools use 128-row
-pages (multiple-of-128 constraint), tests exercise smaller interpreted
-shapes.
+Per-slot lengths (and the paged variant's block tables) are
+scalar-prefetch operands in SMEM.  D is the lane dimension; for the paged
+variant block_k == page size (16 and 128 both compile for TPU v5e).
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 NEG_INF = -1e30
 
@@ -96,10 +98,11 @@ def _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def _kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *, block_k: int, softcap: float):
     """Dense variant: kv block `kb` sits at cache rows [kb*block_k, ...)."""
+    b = pl.program_id(0)
     kblk = pl.program_id(1)
     _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                         kblk=kblk, nk=pl.num_programs(1),
-                        kstart=kblk * block_k, kv_len=kv_len_ref[0],
+                        kstart=kblk * block_k, kv_len=kv_len_ref[b],
                         softcap=softcap)
 
 
@@ -137,38 +140,44 @@ def _paged_kernel_int8(bt_ref, kv_len_ref, ks_ref, vs_ref, q_ref, k_ref,
 def ragged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                             v_cache: jnp.ndarray, kv_len: jnp.ndarray,
                             *, block_k: int = 128, softcap: float = 0.0,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (B, H, D); k/v_cache: (B, S, Kh, D); kv_len: (B,) -> (B, H, D).
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container); on TPU pass interpret=False for the compiled kernel.
+    ``interpret=None`` interprets only on the CPU backend
+    (:func:`repro.kernels.default_interpret`).
     """
     B, H, D = q.shape
     S, Kh = k_cache.shape[1], k_cache.shape[2]
     assert S % block_k == 0, (S, block_k)
-    nk = S // block_k
-    grid = (B, nk)
     kernel = functools.partial(_kernel, block_k=block_k, softcap=softcap)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+
+    def q_map(b, kb, kl):
+        return (b, 0, 0)
+
+    def kv_map(b, kb, kl):
+        return (b, kb, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,       # kv_len
+        grid=(B, S // block_k),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, kb: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, H, D), lambda b, kb: (b, 0, 0)),
-            pl.BlockSpec((None, block_k, Kh, D), lambda b, kb: (b, kb, 0, 0)),
-            pl.BlockSpec((None, block_k, Kh, D), lambda b, kb: (b, kb, 0, 0)),
+            pl.BlockSpec((None, H, D), q_map),
+            pl.BlockSpec((None, block_k, Kh, D), kv_map),
+            pl.BlockSpec((None, block_k, Kh, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, H, D), lambda b, kb: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_specs=pl.BlockSpec((None, H, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, D), jnp.float32),
         ],
-        interpret=interpret,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        interpret=default_interpret() if interpret is None else interpret,
         name="ragged_decode_attention",
-    )(kv_len, q, k_cache, v_cache)
+    )(kv_len.astype(jnp.int32), q, k_cache, v_cache)
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -176,7 +185,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            kv_len: jnp.ndarray, *, softcap: float = 0.0,
                            k_scales: jnp.ndarray = None,
                            v_scales: jnp.ndarray = None,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: Optional[bool] = None) -> jnp.ndarray:
     """Decode attention over a paged KV pool.
 
     q: (B, H, D); k/v_pages: (N, page, Kh, D) physical page pool;
@@ -195,6 +204,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
     prefetch so the kernel dequantises each page in-register right after
     its DMA — the pool stays int8 in HBM, halving (vs bf16; quartering vs
     f32) the decode's memory traffic and doubling effective capacity.
+
+    ``interpret=None`` interprets only on the CPU backend.
     """
     B, H, D = q.shape
     page, Kh = k_pages.shape[1], k_pages.shape[2]
@@ -247,75 +258,66 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
         name="paged_decode_attention",
     )(*scalar_ops, q, k_pages, v_pages)
 
 
 # ---------------------------------------------------------------------------
-# Fused sampling: LM head matmul + greedy/top-k + logsumexp in one pass
+# Fused sampling: LM head matmul + greedy token + logsumexp in one pass
 # ---------------------------------------------------------------------------
 
-def _fused_sample_kernel(x_ref, w_ref, vals_ref, idx_ref, lse_ref,
-                         m_ref, l_ref, tv_ref, ti_ref, *,
-                         block_v: int, top_k: int, vocab: int,
-                         softcap: float):
-    """One (1, Dm) hidden row x one (Dm, block_v) head slice per program.
-    Running logsumexp (m/l scratch) and running top-k (tv/ti scratch)
-    accumulate across the sequential vocab grid axis; the merge keeps the
-    running entries FIRST in the concat so ``lax.top_k``'s stable
-    tie-break (lowest index wins) reproduces ``argmax``'s
-    first-occurrence rule for the greedy token."""
-    vblk = pl.program_id(1)
-    nv = pl.num_programs(1)
+def _fused_sample_kernel(x_ref, w_ref, val_ref, idx_ref, lse_ref, l_ref, *,
+                         block_v: int, vocab: int, softcap: float):
+    """Every (Bp, Dm) hidden row x one (Dm, block_v) head slice per
+    program, so the head streams through VMEM once per call.  The outputs
+    stay resident across the sequential vocab axis and carry the running
+    max logit (val) and its index (idx); l_ref carries the running sum of
+    exp(logit - max).  A block's max replaces the running one only when
+    strictly greater, and inside a block the lowest index at the max
+    wins, which is argmax's first-occurrence rule."""
+    vblk = pl.program_id(0)
 
     @pl.when(vblk == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        val_ref[...] = jnp.full_like(val_ref, NEG_INF)
+        idx_ref[...] = jnp.zeros_like(idx_ref)
         l_ref[...] = jnp.zeros_like(l_ref)
-        tv_ref[...] = jnp.full_like(tv_ref, NEG_INF)
-        ti_ref[...] = jnp.zeros_like(ti_ref)
 
-    x = x_ref[...].astype(jnp.float32)                    # (1, Dm)
+    x = x_ref[...].astype(jnp.float32)                    # (Bp, Dm)
     w = w_ref[...].astype(jnp.float32)                    # (Dm, bv)
-    s = jnp.dot(x, w, preferred_element_type=jnp.float32)  # (1, bv)
+    s = jnp.dot(x, w, preferred_element_type=jnp.float32)  # (Bp, bv)
     if softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
-    col = vblk * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_v), 1)
+    col = vblk * block_v + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < vocab, s, NEG_INF)                # head padding
-    m_prev = m_ref[...]                                   # (1, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(col < vocab, p, 0.0)
+    b_max = jnp.max(s, axis=-1, keepdims=True)            # (Bp, 1)
+    b_idx = jnp.min(jnp.where(s == b_max, col, vocab), axis=-1,
+                    keepdims=True)
+    m_prev = val_ref[...]
+    m_new = jnp.maximum(m_prev, b_max)
+    p = jnp.where(col < vocab, jnp.exp(s - m_new), 0.0)
     l_ref[...] = l_ref[...] * jnp.exp(m_prev - m_new) + jnp.sum(
         p, axis=-1, keepdims=True)
-    m_ref[...] = m_new
-    cat_v = jnp.concatenate([tv_ref[...], s], axis=1)     # (1, K + bv)
-    cat_i = jnp.concatenate([ti_ref[...], col], axis=1)
-    top_v, sel = jax.lax.top_k(cat_v, top_k)
-    tv_ref[...] = top_v
-    ti_ref[...] = jnp.take_along_axis(cat_i, sel, axis=1)
+    idx_ref[...] = jnp.where(b_max > m_prev, b_idx, idx_ref[...])
+    val_ref[...] = m_new
 
-    @pl.when(vblk == nv - 1)
+    @pl.when(vblk == pl.num_programs(0) - 1)
     def _finalize():
-        vals_ref[...] = tv_ref[0]
-        idx_ref[...] = ti_ref[0]
-        lse_ref[...] = (m_ref[...] + jnp.log(
-            jnp.maximum(l_ref[...], 1e-30)))[0]
+        lse_ref[...] = val_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-30))
 
 
-def fused_sample(x: jnp.ndarray, w: jnp.ndarray, *, top_k: int = 1,
-                 block_v: int = 128, softcap: float = 0.0,
-                 interpret: bool = True):
-    """Fused LM-head + sampling epilogue for the paged decode step.
+def fused_sample(x: jnp.ndarray, w: jnp.ndarray, *, block_v: int = 128,
+                 softcap: float = 0.0, interpret: Optional[bool] = None):
+    """Fused LM-head + greedy sampling epilogue for the paged decode step.
 
     x: (B, Dm) final-normed hidden states; w: (Dm, V) head weights.
-    Returns (vals (B, top_k) f32, idx (B, top_k) i32, lse (B, 1) f32):
-    the top-k logits (softcapped), their vocab indices, and the
-    logsumexp over the full vocab — everything greedy/top-k sampling
-    needs (greedy token = idx[:, 0], its logprob = vals[:, 0] - lse[:, 0])
-    without ever materialising the (B, V) logits round-trip.
+    Returns (val (B, 1) f32, idx (B, 1) i32, lse (B, 1) f32): the largest
+    (softcapped) logit, its first vocab index, and the logsumexp over the
+    full vocab — the greedy token is idx[:, 0] and its logprob
+    val[:, 0] - lse[:, 0] — without materialising the (B, V) logits.
+    Rows are padded to a multiple of 8 (the sublane tile).
+    ``interpret=None`` interprets only on the CPU backend.
     """
     B, Dm = x.shape
     V = w.shape[1]
@@ -323,32 +325,27 @@ def fused_sample(x: jnp.ndarray, w: jnp.ndarray, *, top_k: int = 1,
     nv = -(-V // block_v)
     if V % block_v:
         w = jnp.pad(w, ((0, 0), (0, nv * block_v - V)))
+    Bp = -(-B // 8) * 8
+    if Bp != B:
+        x = jnp.pad(x, ((0, Bp - B), (0, 0)))
     kernel = functools.partial(_fused_sample_kernel, block_v=block_v,
-                               top_k=top_k, vocab=V, softcap=softcap)
-    vals, idx, lse = pl.pallas_call(
+                               vocab=V, softcap=softcap)
+    row = pl.BlockSpec((Bp, 1), lambda vb: (0, 0))
+    val, idx, lse = pl.pallas_call(
         kernel,
-        grid=(B, nv),
+        grid=(nv,),
         in_specs=[
-            pl.BlockSpec((None, Dm), lambda b, vb: (b, 0)),
-            pl.BlockSpec((Dm, block_v), lambda b, vb: (0, vb)),
+            pl.BlockSpec((Bp, Dm), lambda vb: (0, 0)),
+            pl.BlockSpec((Dm, block_v), lambda vb: (0, vb)),
         ],
-        out_specs=[
-            pl.BlockSpec((None, top_k), lambda b, vb: (b, 0)),
-            pl.BlockSpec((None, top_k), lambda b, vb: (b, 0)),
-            pl.BlockSpec((None, 1), lambda b, vb: (b, 0)),
-        ],
+        out_specs=[row, row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((B, top_k), jnp.float32),
-            jax.ShapeDtypeStruct((B, top_k), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, top_k), jnp.float32),
-            pltpu.VMEM((1, top_k), jnp.int32),
-        ],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((Bp, 1), jnp.float32)],
+        interpret=default_interpret() if interpret is None else interpret,
         name="fused_sample",
     )(x, w)
-    return vals, idx, lse
+    return val[:B], idx[:B], lse[:B]

@@ -76,12 +76,13 @@ def paged_decode_attention_int8_ref(q, k_pages, v_pages, k_scales, v_scales,
         softcap=softcap)
 
 
-def fused_sample_ref(x, w, top_k: int = 1, softcap: float = 0.0):
+def fused_sample_ref(x, w, softcap: float = 0.0):
     """Two-pass oracle for the fused sampling kernel: materialise the
-    full (B, V) logits, then top-k + logsumexp."""
+    full (B, V) logits, then max / argmax / logsumexp, each (B, 1)."""
     logits = jnp.einsum("bd,dv->bv", x, w).astype(jnp.float32)
     if softcap > 0:
         logits = jnp.tanh(logits / softcap) * softcap
-    vals, idx = jax.lax.top_k(logits, top_k)
+    val = jnp.max(logits, axis=-1, keepdims=True)
+    idx = jnp.argmax(logits, axis=-1, keepdims=True).astype(jnp.int32)
     lse = jax.nn.logsumexp(logits, axis=-1, keepdims=True)
-    return vals, idx.astype(jnp.int32), lse
+    return val, idx, lse

@@ -7,32 +7,23 @@ touches jax device state — dryrun.py must set
 from __future__ import annotations
 
 import jax
-
-# jax >= 0.5 exposes jax.sharding.AxisType and make_mesh(axis_types=...);
-# older releases have neither (every axis is implicitly Auto).
-try:
-    from jax.sharding import AxisType
-except ImportError:          # pragma: no cover - depends on jax version
-    AxisType = None
+from jax.sharding import AxisType
 
 
-def make_compat_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types across jax versions."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_auto_mesh(shape, axes):
+    """jax.make_mesh with every axis of Auto type (GSPMD-propagated)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
-    return make_compat_mesh((1, 1), ("data", "model"))
+    return make_auto_mesh((1, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants (per chip) — roofline denominators

@@ -21,6 +21,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -137,12 +138,6 @@ def moe_mlp_ep(p: Params, cfg: ModelConfig, x: jnp.ndarray, mesh,
     its own token slice; per-expert capacity buffers are exchanged with
     all_to_all so the device owning expert e computes all its tokens.
     """
-    try:                       # jax >= 0.6: top-level export, check_vma kwarg
-        from jax import shard_map
-        check_kw = {"check_vma": False}
-    except ImportError:        # older jax: experimental home, check_rep kwarg
-        from jax.experimental.shard_map import shard_map
-        check_kw = {"check_rep": False}
     m = cfg.moe
     E = m.num_experts
     n_model = mesh.shape[model_axis]
@@ -216,7 +211,7 @@ def moe_mlp_ep(p: Params, cfg: ModelConfig, x: jnp.ndarray, mesh,
         p_moe = dict(p_moe, w_in=padw(p["w_in"]), w_gate=padw(p["w_gate"]),
                      w_out=padw(p["w_out"]))
     y, aux = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **check_kw)(p_moe, x)
+                       out_specs=out_specs, check_vma=False)(p_moe, x)
     if "shared" in p:
         from repro.models.layers import mlp as dense_mlp
         y = y + dense_mlp(p["shared"], x, "silu", True)

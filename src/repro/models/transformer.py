@@ -221,7 +221,7 @@ def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
 
     ``return_hidden=True`` returns the final-normed hidden state (B, d)
     instead of logits — the fused-sampling decode path computes the LM
-    head blockwise in the same pass as top-k/lse, so the full (B, V)
+    head blockwise in the same pass as max/lse, so the full (B, V)
     logits round-trip never materialises (see rollout/engine.py)."""
     if embeds is None:
         x = embed_tokens(params, cfg, token[:, None])

@@ -61,6 +61,8 @@ def sft_warmup(model: Model, params, examples: Sequence[Tuple[List[int],
                pad_id: int, steps: int = 200, batch_size: int = 32,
                lr: float = 1e-3, seed: int = 0, width: int = 96):
     from repro.train.optimizer import adamw_update, init_opt_state
+    if steps <= 0:
+        return params, []
     opt_cfg = AdamWConfig(lr=lr, grad_clip=1.0)
     opt_state = init_opt_state(params, opt_cfg)
     rng = np.random.RandomState(seed)
@@ -199,6 +201,10 @@ class SessionConfig:
     lr: float = 3e-4
     temperature: float = 1.0
     seed: int = 0
+    # the slot path's model: a ModelConfig (e.g. a published config from
+    # repro.configs, random-init from `seed`), or None for
+    # tiny_lm_config(len(task vocab), d_model, layers)
+    model: Optional[ModelConfig] = None
     d_model: int = 128
     layers: int = 4
     eval_every: int = 4               # updates between evals
@@ -399,8 +405,13 @@ class RLSession:
                                        autoscaler=build_autoscaler())
 
         if cfg.engine == "slot":
-            model = build_model(tiny_lm_config(len(vocab), cfg.d_model,
-                                               cfg.layers))
+            model_cfg = cfg.model or tiny_lm_config(len(vocab), cfg.d_model,
+                                                    cfg.layers)
+            if model_cfg.vocab_size < len(vocab):
+                raise ValueError(
+                    f"model vocab {model_cfg.vocab_size} cannot embed the "
+                    f"{cfg.task!r} task's {len(vocab)} token ids")
+            model = build_model(model_cfg)
             params = model.init_params(jax.random.PRNGKey(cfg.seed))
             gen = spec.make_generator(cfg.seed)
             sft_examples = [gen.sft_example() for _ in range(2048)]
@@ -421,20 +432,43 @@ class RLSession:
                                 max_len=cfg.max_total_len,
                                 advantage_kind=cfg.advantage_kind,
                                 responses_per_prompt=cfg.responses_per_prompt)
-            # partial mode keeps resident KV across weight syncs (the
-            # paper's cache mechanism; recorded logprobs stay exact as
-            # pi_old); on-policy re-rolls must re-prefill under the fresh
-            # policy, or the prompt KV would bias the new rollouts
-            engine = replicated(lambda i, cap: SlotEngine(
-                model, trainer.params, capacity=cap,
-                max_total_len=cfg.max_total_len,
-                max_gen_len=cfg.max_gen_len,
-                eos_id=vocab.eos_id, pad_id=vocab.pad_id,
-                temperature=cfg.temperature, seed=cfg.seed + i,
-                kv_retain_across_sync=(Mode(cfg.mode) == Mode.PARTIAL),
-                packed_prefill=cfg.packed_prefill,
-                fused_sampling=cfg.fused_sampling,
-                kv_quant=cfg.kv_quant))
+            devices = jax.local_devices()
+
+            def build_slot(i: int, cap: int) -> SlotEngine:
+                """Replica i runs on local device i (mod the count), so
+                four replicas on a four-chip host hold four separate KV
+                pools.  Off the default device a replica holds its own
+                copy of the weights, re-copied when the trainer's step
+                (the policy version) advances."""
+                device = devices[i % len(devices)]
+                params_fn = trainer.params
+                if device == devices[0]:
+                    device = None
+                else:
+                    held = {"step": None, "params": None}
+
+                    def params_fn():
+                        if held["step"] != trainer.state.step:
+                            held["params"] = jax.device_put(
+                                trainer.params(), device)
+                            held["step"] = trainer.state.step
+                        return held["params"]
+                # partial mode keeps resident KV across weight syncs (the
+                # paper's cache mechanism; recorded logprobs stay exact as
+                # pi_old); on-policy re-rolls must re-prefill under the
+                # fresh policy, or the prompt KV would bias the new rollouts
+                return SlotEngine(
+                    model, params_fn, capacity=cap, device=device,
+                    max_total_len=cfg.max_total_len,
+                    max_gen_len=cfg.max_gen_len,
+                    eos_id=vocab.eos_id, pad_id=vocab.pad_id,
+                    temperature=cfg.temperature, seed=cfg.seed + i,
+                    kv_retain_across_sync=(Mode(cfg.mode) == Mode.PARTIAL),
+                    packed_prefill=cfg.packed_prefill,
+                    fused_sampling=cfg.fused_sampling,
+                    kv_quant=cfg.kv_quant)
+
+            engine = replicated(build_slot)
             eval_gen = spec.make_generator(9999)
             eval_set = eval_gen.batch(cfg.eval_size)
 

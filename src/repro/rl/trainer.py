@@ -185,8 +185,14 @@ class RLTrainer:
         self.max_len = max_len
         self.advantage_kind = advantage_kind
         self.responses_per_prompt = responses_per_prompt
+        # params and optimizer state are donated, so the step updates them
+        # in place: at Qwen3-0.6B width a step that kept the old state
+        # beside the new one would need 16.9 GB for 8x512 tokens on a
+        # 15.75 GB TPU v5e (compile-time memory analysis).  Nothing else
+        # may hold on to trainer.params() across an update.
         self._step_jit = jax.jit(make_train_step(model, self.loss_cfg,
-                                                 self.opt_cfg))
+                                                 self.opt_cfg),
+                                 donate_argnums=(0, 1))
         self.history: List[Dict] = []
 
     def params(self):

@@ -125,7 +125,11 @@ class SlotEngine:
                  kv_retain_across_sync: bool = True,
                  packed_prefill: bool = False,
                  fused_sampling: bool = False,
-                 kv_quant: Optional[str] = None):
+                 kv_quant: Optional[str] = None,
+                 device: Optional[jax.Device] = None):
+        """``device``: where the KV pool lives (None: JAX's default
+        device).  The pool is committed there, so every jitted step runs
+        there too; ``params_fn`` must return weights on the same device."""
         self.model = model
         self.params_fn = params_fn
         self.capacity = capacity
@@ -159,38 +163,42 @@ class SlotEngine:
             assert paged, "fused_sampling requires the paged layout"
         self.prefill_launches = 0       # one per prefill kernel launch
         self.slots = SlotTable(capacity)
-        if paged:
-            self.page_size = page_size
-            self._pages_per_seq = -(-max_total_len // page_size)
-            # default: dense-equivalent capacity + COW headroom + garbage
-            self.num_pages = num_pages or (
-                capacity * self._pages_per_seq + capacity + 1)
-            self.cache = model.init_cache(self.num_pages, page_size)
-            if kv_quant == "int8":
-                # quantized page pool: int8 storage + one f32 scale per
-                # (layer, page) plane — ~4x (f32) / ~2x (bf16) the token
-                # capacity at equal bytes
-                nl = self.cache["k"].shape[0]
-                self.cache = {name: jnp.zeros(arr.shape, jnp.int8)
-                              for name, arr in self.cache.items()}
-                self.kv_scales = {
-                    "k": jnp.ones((nl, self.num_pages), jnp.float32),
-                    "v": jnp.ones((nl, self.num_pages), jnp.float32)}
+        with jax.default_device(device):
+            if paged:
+                self.page_size = page_size
+                self._pages_per_seq = -(-max_total_len // page_size)
+                # default: dense-equivalent capacity + COW headroom + garbage
+                self.num_pages = num_pages or (
+                    capacity * self._pages_per_seq + capacity + 1)
+                self.cache = model.init_cache(self.num_pages, page_size)
+                if kv_quant == "int8":
+                    # quantized page pool: int8 storage + one f32 scale per
+                    # (layer, page) plane — ~4x (f32) / ~2x (bf16) the token
+                    # capacity at equal bytes
+                    nl = self.cache["k"].shape[0]
+                    self.cache = {name: jnp.zeros(arr.shape, jnp.int8)
+                                  for name, arr in self.cache.items()}
+                    self.kv_scales = {
+                        "k": jnp.ones((nl, self.num_pages), jnp.float32),
+                        "v": jnp.ones((nl, self.num_pages), jnp.float32)}
+                else:
+                    self.kv_scales = {}
+                # retain=True keeps resident/shared KV across weight syncs
+                # (PipelineRL/APRIL approximation, counted in stale_kv_reuses);
+                # retain=False restores dense fresh-prefill-after-update
+                # semantics — use it for on-policy re-rolls (see rl/session.py)
+                self.kv = PagedKVCache(self.num_pages, page_size,
+                                       extra_rows=model.prefill_extra,
+                                       retain_across_sync=kv_retain_across_sync)
+                self._paged_decode_cache: Dict[Tuple, Callable] = {}
             else:
                 self.kv_scales = {}
-            # retain=True keeps resident/shared KV across weight syncs
-            # (PipelineRL/APRIL approximation, counted in stale_kv_reuses);
-            # retain=False restores dense fresh-prefill-after-update
-            # semantics — use it for on-policy re-rolls (see rl/session.py)
-            self.kv = PagedKVCache(self.num_pages, page_size,
-                                   extra_rows=model.prefill_extra,
-                                   retain_across_sync=kv_retain_across_sync)
-            self._paged_decode_cache: Dict[Tuple, Callable] = {}
-        else:
-            self.kv_scales = {}
-            self.cache = model.init_cache(capacity, max_total_len)
-            self.kv = None
-            self._decode_jit = jax.jit(self._decode_fn)
+                self.cache = model.init_cache(capacity, max_total_len)
+                self.kv = None
+                self._decode_jit = jax.jit(self._decode_fn)
+        if device is not None:
+            self.cache, self.kv_scales = jax.device_put(
+                (self.cache, self.kv_scales), device)
         # int8 and fp cache configs must not collide on a (width, batch)
         # bucket — the KV dtype is part of every compile-cache key
         self._kv_dtype_key = kv_quant or jnp.dtype(

@@ -53,9 +53,9 @@ def test_dispatch_indices_capacity_order():
 
 def test_ep_path_matches_dense_single_device():
     """shard_map EP path on a 1x1 mesh == dense-dispatch path."""
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_auto_mesh
     cfg, p = _setup(capacity_factor=8.0, experts=4, k=2)
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     x = jax.random.normal(jax.random.fold_in(KEY, 3), (2, 16, cfg.d_model))
     y_ep, aux_ep = MOE.moe_mlp_ep(p, cfg, x, mesh)
     y_d, _ = MOE.moe_mlp_dense(p, cfg, x)
