@@ -24,7 +24,10 @@ batch shapes, and each replica's page pool and weights must live on its
 own device.
 
 Earlier lines of stdout are informational (wall and compile seconds,
-tokens, peak device bytes), not benchmark metrics.  The last line is
+tokens, peak device bytes, and from the program's span recorder
+``repro.core.trace``: compiles by the span that caused them, seconds and
+self seconds by span, and the trajectories' time running and waiting for
+their update), not benchmark metrics.  The last line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
 Without a TPU, or when any check fails, the script exits non-zero and
 prints no such line.
@@ -35,12 +38,14 @@ import argparse
 import json
 import math
 import os
+import statistics
 import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
+from repro.core import trace  # noqa: E402
 from repro.core.buffer import BufferEntry, Mode  # noqa: E402
 from repro.rl.session import SessionConfig  # noqa: E402
 
@@ -74,21 +79,33 @@ def session_config(model_cfg) -> SessionConfig:
         eval_every=1 << 30, eval_size=2)
 
 
-class CompileClock:
-    """Sums XLA backend compile time reported through jax.monitoring."""
+def _p90(values) -> float | None:
+    values = list(values)
+    return statistics.quantiles(values, n=10)[8] if len(values) >= 2 else None
 
-    EVENT = "/jax/core/compile/backend_compile_duration"
 
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        self.count = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event == self.EVENT:
-            self.seconds += duration
-            self.count += 1
+def trace_facts(rec: trace.Recorder, got: trace.Taken) -> dict:
+    """What the program's recorder saw: backend compiles (and the span
+    open during each); per span name its entries, seconds and self seconds
+    (less its child spans); and of the trajectories an update consumed,
+    how many were harvested and resumed, with the p90 seconds from first
+    running to done and from done to the consuming update."""
+    own = {}
+    for s, ns in zip(got.spans, trace.self_ns(got.spans)):
+        own[s.name] = own.get(s.name, 0.0) + ns * 1e-9
+    consumed = [s for s in got.stamps.values() if "consume" in s]
+    return {"compile_s": sum(c[1] for c in got.compiles.values()),
+            "compiles": sum(c[0] for c in got.compiles.values()),
+            "compiles_by_span": {k: c[0] for k, c in got.compiles.items()},
+            "span_s": {k: [rec.count[k], rec.total[k], own.get(k, 0.0)]
+                       for k in rec.total},
+            "trajectories": {
+                "consumed": len(consumed),
+                "harvested": sum("harvest" in s for s in consumed),
+                "run_p90_s": _p90(trace.between(got.stamps, "running",
+                                                "done").values()),
+                "done_wait_p90_s": _p90(trace.between(got.stamps, "done",
+                                                      "consume").values())}}
 
 
 def memory_stats() -> dict:
@@ -308,7 +325,7 @@ def main(argv=None) -> int:
         return 1
     from repro.compile_cache import enable_compile_cache
     print(json.dumps({"compile_cache": enable_compile_cache()}))
-    clock = CompileClock()
+    rec = trace.enable()
     try:
         if args.chips == 4:
             print(json.dumps(replica_phase(qwen3_0_6b(), 4)))
@@ -318,8 +335,10 @@ def main(argv=None) -> int:
     except SmokeFailure as e:
         print(f"chip_smoke: check failed: {e}", file=sys.stderr)
         return 1
-    print(json.dumps({"compile_s": clock.seconds, "compiles": clock.count,
-                      "memory": memory_stats()}))
+    finally:
+        trace.disable()
+    print(json.dumps(dict(trace_facts(rec, rec.take()),
+                          memory=memory_stats())))
     print(json.dumps({"ok": True, "device": {
         "platform": devs[0].platform, "kind": devs[0].device_kind,
         "count": len(devs)}}))

@@ -13,6 +13,8 @@ import enum
 import itertools
 from typing import Any, Dict, Iterable, List, Optional, Sequence
 
+from repro.core import trace
+
 
 class Mode(str, enum.Enum):
     ON_POLICY = "on_policy"   # discard partial generations; re-roll prompts
@@ -137,6 +139,7 @@ class StatefulRolloutBuffer:
             e = self.entries[uid]
             assert e.state == EntryState.PENDING, (uid, e.state)
             e.state = EntryState.RUNNING
+            trace.stamp(uid, "running")
 
     def record_tokens(self, uid: int, tokens: Sequence[int],
                       logprobs: Sequence[float], version: int) -> None:
@@ -151,6 +154,7 @@ class StatefulRolloutBuffer:
         assert e.state == EntryState.RUNNING
         e.state = EntryState.DONE
         e.finish_reason = finish_reason
+        trace.stamp(uid, "done")
 
     def scavenge(self, uid: int) -> None:
         """Early termination hit this entry: return it to PENDING.
@@ -169,6 +173,7 @@ class StatefulRolloutBuffer:
             e.versions.clear()
         e.interruptions += 1
         e.state = EntryState.PENDING
+        trace.stamp(uid, "harvest")
 
     def consume(self, uids: Iterable[int]) -> List[BufferEntry]:
         out = []
@@ -176,6 +181,7 @@ class StatefulRolloutBuffer:
             e = self.entries[uid]
             assert e.state == EntryState.DONE, (uid, e.state)
             e.state = EntryState.CONSUMED
+            trace.stamp(uid, "consume")
             out.append(e)
         return out
 

@@ -46,6 +46,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, List, Optional, Sequence
 
+from repro.core import trace
 from repro.core.buffer import (BufferEntry, EntryState, Mode,
                                StatefulRolloutBuffer)
 from repro.core.engine_api import EngineProtocol, StepEvent
@@ -302,14 +303,15 @@ class RolloutOrchestrator:
         to PENDING (on-policy discards its tokens, partial keeps them).
         Shared by the epoch harvest below and the serving tier's
         continuous-batching harvest.  Returns the interrupted uids."""
-        interrupted = self.engine.interrupt()
-        for uid in interrupted:
-            e = self.buffer.entries[uid]
-            if self.buffer.mode == Mode.ON_POLICY:
-                self.metrics.tokens_discarded += e.gen_len
-            self.buffer.scavenge(uid)
-        self.metrics.harvests += 1
-        return interrupted
+        with trace.span("sched.harvest"):
+            interrupted = self.engine.interrupt()
+            for uid in interrupted:
+                e = self.buffer.entries[uid]
+                if self.buffer.mode == Mode.ON_POLICY:
+                    self.metrics.tokens_discarded += e.gen_len
+                self.buffer.scavenge(uid)
+            self.metrics.harvests += 1
+            return interrupted
 
     def rollout_until_harvest(self) -> None:
         while True:
@@ -319,13 +321,17 @@ class RolloutOrchestrator:
             # harvest_now a stale cap for the rest of the epoch
             threshold = min(self.cfg.resolved_threshold(),
                             len(self.buffer.unconsumed()))
-            self._fill_engine()
+            with trace.span("sched.fill"):
+                self._fill_engine()
             if not self.engine.active_uids():
                 break
             t0 = self.engine.clock
             events = self.engine.step()
-            self._apply_events(events, t0)
-            if self.policy.harvest_now(self._view(threshold)):
+            with trace.span("sched.apply_events"):
+                self._apply_events(events, t0)
+            with trace.span("sched.harvest_check"):
+                harvest = self.policy.harvest_now(self._view(threshold))
+            if harvest:
                 break
         if not self.policy.early_termination:
             return   # wait-for-all: the loop above drained the engine
@@ -347,6 +353,10 @@ class RolloutOrchestrator:
         with overlap submissions queue on the trainer timeline and land
         via ``poll`` during subsequent rollout steps.  Returns the number
         of updates completed during this call."""
+        with trace.span("sched.train_ready"):
+            return self._train_ready(final)
+
+    def _train_ready(self, final: bool) -> int:
         ready = self.policy.order_ready(self.buffer.done(), self._view())
         n_updates = 0
         while len(ready) >= self.cfg.update_batch or (
@@ -394,7 +404,8 @@ class RolloutOrchestrator:
             self.metrics.batch_skipped += int(
                 o.result.metrics.get("entries_skipped", 0))
         self.version += 1
-        self.engine.sync_weights(self.version)
+        with trace.span("sched.sync"):
+            self.engine.sync_weights(self.version)
         self.metrics.updates += 1
         if (self._overlap and self.buffer.mode == Mode.ON_POLICY
                 and self.engine.active_uids()):

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.buffer import BufferEntry
 from repro.core.orchestrator import UpdateRequest, UpdateResult
 from repro.distributed.sharding import shard_update_batch
@@ -199,13 +200,20 @@ class RLTrainer:
         return self.state.params
 
     def update(self, entries: List[BufferEntry], version: int) -> Dict:
-        batch, info = entries_to_batch(
-            entries, self.reward_fn, self.pad_id, self.max_len,
-            self.advantage_kind, current_version=version)
-        params, opt_state, metrics = self._step_jit(
-            self.state.params, self.state.opt_state, batch)
+        with trace.span("trainer.update"):
+            return self._update(entries, version)
+
+    def _update(self, entries: List[BufferEntry], version: int) -> Dict:
+        with trace.span("trainer.batch"):
+            batch, info = entries_to_batch(
+                entries, self.reward_fn, self.pad_id, self.max_len,
+                self.advantage_kind, current_version=version)
+        with trace.span("trainer.step"):
+            params, opt_state, metrics = self._step_jit(
+                self.state.params, self.state.opt_state, batch)
         self.state = TrainState(params, opt_state, self.state.step + 1)
-        rec = {k: float(v) for k, v in metrics.items()}
+        with trace.span("trainer.readback"):
+            rec = {k: float(v) for k, v in metrics.items()}
         rec.update(info)
         rec["version"] = version
         rec["step"] = self.state.step

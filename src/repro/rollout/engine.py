@@ -57,6 +57,7 @@ Hot-path notes
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -64,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.buffer import BufferEntry
 from repro.core.engine_api import SlotTable, StepEvent
 from repro.core.kv_cache import PagedKVCache, PoolExhausted
@@ -105,6 +107,16 @@ def cache_put(cache: Dict[str, jnp.ndarray], sub: Dict[str, jnp.ndarray],
         idx = (slice(None),) * ax + (slots,)
         out[name] = arr.at[idx].set(sl.astype(arr.dtype))
     return out
+
+
+def _named(scope: str, fn: Callable) -> Callable:
+    """``fn`` with its ops under ``jax.named_scope(scope)``; a jitted
+    program keeps ``fn``'s name."""
+    @functools.wraps(fn)
+    def scoped(*args):
+        with jax.named_scope(scope):
+            return fn(*args)
+    return scoped
 
 
 def supports_paging(model: Model) -> bool:
@@ -237,14 +249,16 @@ class SlotEngine:
     def submit(self, entries: Sequence[BufferEntry], version: int) -> None:
         if not entries:
             return
-        slots = self.slots.allocate(len(entries))
-        seqs = [list(e.prompt) + list(e.generated) for e in entries]
-        # prefill everything but the last token; it is fed on the next step
-        pre = [s[:-1] for s in seqs]
-        if self.paged:
-            self._submit_paged(entries, slots, seqs, pre)
-        else:
-            self._submit_dense(entries, slots, seqs, pre)
+        with trace.span("engine.submit"):
+            slots = self.slots.allocate(len(entries))
+            seqs = [list(e.prompt) + list(e.generated) for e in entries]
+            # prefill everything but the last token; it is fed on the next
+            # step
+            pre = [s[:-1] for s in seqs]
+            if self.paged:
+                self._submit_paged(entries, slots, seqs, pre)
+            else:
+                self._submit_dense(entries, slots, seqs, pre)
 
     def _submit_dense(self, entries, slots, seqs, pre) -> None:
         k = len(entries)
@@ -348,8 +362,9 @@ class SlotEngine:
                 rows.append(i)
                 blks.append(j)
                 phys.append(page)
-        self._scatter_pages(sub_cache, np.asarray(rows), np.asarray(blks),
-                            np.asarray(phys))
+        with trace.span("engine.page_scatter"):
+            self._scatter_pages(sub_cache, np.asarray(rows), np.asarray(blks),
+                                np.asarray(phys))
 
     def _prefill_to_pages_packed(self, entries, pres) -> None:
         """Packed ragged prefill: bin-pack page-aligned prefix spans into
@@ -401,9 +416,10 @@ class SlotEngine:
         key = ("packed", width, kb, self._kv_dtype_key)
         fn = self._prefill_cache.get(key)
         if fn is None:
-            fn = jax.jit(self.model.prefill_packed)
+            fn = jax.jit(_named("prefill", self.model.prefill_packed))
             self._prefill_cache[key] = fn
-        _, sub_cache = fn(params, batch, sub_cache)
+        with trace.span("engine.prefill"):
+            _, sub_cache = fn(params, batch, sub_cache)
         self.prefill_launches += 1
 
         rows, blks, phys = [], [], []
@@ -413,8 +429,9 @@ class SlotEngine:
                 rows.append(row_of[i])
                 blks.append(offset[i] // P + j)
                 phys.append(page)
-        self._scatter_pages(sub_cache, np.asarray(rows), np.asarray(blks),
-                            np.asarray(phys))
+        with trace.span("engine.page_scatter"):
+            self._scatter_pages(sub_cache, np.asarray(rows), np.asarray(blks),
+                                np.asarray(phys))
 
     def _scatter_pages(self, sub_cache, rows, blks, phys) -> None:
         """Scatter prefilled KV page blocks into the pool at ``phys``
@@ -472,10 +489,11 @@ class SlotEngine:
         key = (width, kb, self._kv_dtype_key)
         fn = self._prefill_cache.get(key)
         if fn is None:
-            fn = jax.jit(self.model.prefill)
+            fn = jax.jit(_named("prefill", self.model.prefill))
             self._prefill_cache[key] = fn
         self.prefill_launches += 1
-        return fn(params, batch, cache)
+        with trace.span("engine.prefill"):
+            return fn(params, batch, cache)
 
     # -- decode ---------------------------------------------------------------
 
@@ -535,7 +553,16 @@ class SlotEngine:
         ``kernels.ops.paged_decode_attention_int8``) and requantize the
         written page on scatter with a monotone-nondecreasing scale, so a
         page whose amax did not grow round-trips its old cells exactly.
+
+        Its ops sit under the named scopes ``decode/gather_kv``,
+        ``decode/model``, ``decode/sample`` and ``decode/write_page``.
         """
+        with jax.named_scope("decode"):
+            return self._paged_decode_body(params, token, cache, scales, bt,
+                                           kv_len, key)
+
+    def _paged_decode_body(self, params, token, cache, scales, bt, kv_len,
+                           key):
         P = self.page_size
         B, nb = bt.shape
         quant = self.kv_quant == "int8"
@@ -550,16 +577,29 @@ class SlotEngine:
                 g = g.astype(self.model.cfg.compute_dtype)
             return g.reshape(pages.shape[0], B, nb * P, *pages.shape[3:])
 
-        view = {"k": gather(cache["k"], scales.get("k")),
-                "v": gather(cache["v"], scales.get("v"))}
+        with jax.named_scope("gather_kv"):
+            view = {"k": gather(cache["k"], scales.get("k")),
+                    "v": gather(cache["v"], scales.get("v"))}
         if self.fused_sampling and self.temperature == 0:
-            hidden, view = self.model.decode_step(params, token, view,
-                                                  kv_len, return_hidden=True)
-            sampled, lp = self._fused_greedy(params, hidden)
+            with jax.named_scope("model"):
+                hidden, view = self.model.decode_step(
+                    params, token, view, kv_len, return_hidden=True)
+            with jax.named_scope("sample"):
+                sampled, lp = self._fused_greedy(params, hidden)
         else:
-            logits, view = self.model.decode_step(params, token, view,
-                                                  kv_len)
-            sampled, lp = self._sample(logits, key)
+            with jax.named_scope("model"):
+                logits, view = self.model.decode_step(params, token, view,
+                                                      kv_len)
+            with jax.named_scope("sample"):
+                sampled, lp = self._sample(logits, key)
+        with jax.named_scope("write_page"):
+            cache, scales = self._write_page(view, cache, scales, bt, kv_len)
+        return sampled, lp, cache, scales
+
+    def _write_page(self, view, cache, scales, bt, kv_len):
+        """Each slot's page holding its newest token, from the dense view
+        back to its physical page (int8: requantized, scales monotone)."""
+        P = self.page_size
         blk = kv_len // P
 
         def take_page(x, b):                    # x: (L, S, Kh, D) one slot
@@ -568,7 +608,7 @@ class SlotEngine:
         k_new = jax.vmap(take_page, in_axes=(1, 0), out_axes=1)(view["k"], blk)
         v_new = jax.vmap(take_page, in_axes=(1, 0), out_axes=1)(view["v"], blk)
         phys = jnp.take_along_axis(bt, blk[:, None], axis=1)[:, 0]
-        if quant:
+        if self.kv_quant == "int8":
             for name, new in (("k", k_new), ("v", v_new)):
                 new = new.astype(jnp.float32)
                 amax = jnp.max(jnp.abs(new), axis=(2, 3, 4))  # (L, B)
@@ -587,7 +627,7 @@ class SlotEngine:
                 "v": cache["v"].at[:, phys].set(
                     v_new.astype(cache["v"].dtype)),
             }
-        return sampled, lp, cache, scales
+        return cache, scales
 
     def _paged_decode(self, params, token, cache, bt, kv_len, key):
         fused = self.fused_sampling and self.temperature == 0
@@ -610,32 +650,52 @@ class SlotEngine:
                               for name, arr in self.kv_scales.items()}
 
     def step(self) -> List[StepEvent]:
+        """One decode step of every active slot.  Its spans split it in
+        order: ``prepare`` (copy-on-write, block table, key, uploads),
+        ``launch`` (dispatch of the decode program), ``wait`` (the sampled
+        tokens reaching the host) and ``events`` (page append, slot
+        bookkeeping, page release, the events)."""
         t = self.slots
         act = t.active_indices()
         if act.size == 0:
             return []
-        params = self.params_fn()
-        self._key, sub = jax.random.split(self._key)
-        kv_len = np.where(t.active, t.kv_len, 0).astype(np.int32)
-        if self.paged:
-            uids_act = t.uid[act].tolist()
-            copies = self.kv.prepare_step(uids_act, t.kv_len[act].tolist())
-            if copies:
-                self._copy_pages(copies)
-            nb = min(next_pow2(max(1, self.kv.max_blocks(uids_act))),
-                     self._pages_per_seq)
-            bt = jnp.asarray(self.kv.block_table(t.uid.tolist(), nb))
-            sampled, lp, self.cache, self.kv_scales = self._paged_decode(
-                params, jnp.asarray(t.next_token), self.cache, bt,
-                jnp.asarray(kv_len), sub)
-            self.kv.append_tokens(uids_act, t.next_token[act].tolist())
-        else:
-            sampled, lp, self.cache = self._decode_jit(
-                params, jnp.asarray(t.next_token), self.cache,
-                jnp.asarray(kv_len), jnp.asarray(t.kv_start), sub)
-        sampled = np.asarray(sampled)
-        lp = np.asarray(lp)
+        with trace.span("engine.step"):
+            with trace.span("engine.step.prepare"):
+                params = self.params_fn()
+                self._key, sub = jax.random.split(self._key)
+                kv_len = jnp.asarray(
+                    np.where(t.active, t.kv_len, 0).astype(np.int32))
+                token = jnp.asarray(t.next_token)
+                if self.paged:
+                    uids_act = t.uid[act].tolist()
+                    copies = self.kv.prepare_step(uids_act,
+                                                  t.kv_len[act].tolist())
+                    if copies:
+                        self._copy_pages(copies)
+                    nb = min(next_pow2(max(1, self.kv.max_blocks(uids_act))),
+                             self._pages_per_seq)
+                    bt = jnp.asarray(self.kv.block_table(t.uid.tolist(), nb))
+                else:
+                    kv_start = jnp.asarray(t.kv_start)
+            with trace.span("engine.step.launch"):
+                if self.paged:
+                    sampled, lp, self.cache, self.kv_scales = \
+                        self._paged_decode(params, token, self.cache, bt,
+                                           kv_len, sub)
+                else:
+                    sampled, lp, self.cache = self._decode_jit(
+                        params, token, self.cache, kv_len, kv_start, sub)
+            with trace.span("engine.step.wait"):
+                sampled = np.asarray(sampled)
+                lp = np.asarray(lp)
+            with trace.span("engine.step.events"):
+                if self.paged:
+                    self.kv.append_tokens(uids_act,
+                                          t.next_token[act].tolist())
+                return self._step_events(act, sampled, lp)
 
+    def _step_events(self, act, sampled, lp) -> List[StepEvent]:
+        t = self.slots
         # vectorized bookkeeping over the active slots (ascending order)
         t.kv_len[act] += 1
         t.gen_count[act] += 1
@@ -658,12 +718,13 @@ class SlotEngine:
                                           done.tolist(), reasons.tolist())]
 
     def interrupt(self, uids: Optional[Sequence[int]] = None) -> List[int]:
-        sel = self.slots.select(uids)
-        out = [int(u) for u in self.slots.uid[sel]]
-        self.slots.release(sel)
-        if self.paged:
-            self.kv.deactivate_many(out)   # keep pages resident for resume
-        return out
+        with trace.span("engine.interrupt"):
+            sel = self.slots.select(uids)
+            out = [int(u) for u in self.slots.uid[sel]]
+            self.slots.release(sel)
+            if self.paged:
+                self.kv.deactivate_many(out)   # keep pages resident
+            return out
 
     def shutdown(self) -> None:
         """Fence the engine (killed or scaled-down replica): release

@@ -32,6 +32,46 @@ def test_session_phase_at_tiny_width():
     assert facts["resumed_without_prefill"] > 0
 
 
+def test_trace_facts_count_compiles_spans_and_waits():
+    """chip_smoke counts compiles with the program's recorder: by the span
+    open during each, beside span seconds (whole and self) and the
+    trajectories' running and done-to-update times."""
+    import time
+    import jax.numpy as jnp
+    from repro.core import trace
+    from repro.core.buffer import Mode, StatefulRolloutBuffer
+    rec = trace.enable()
+    try:
+        with trace.span("trainer.update"):
+            with trace.span("trainer.step"):
+                jax.jit(lambda x: x * 5.0 - 0.5)(
+                    jnp.ones(3)).block_until_ready()
+        buf = StatefulRolloutBuffer(Mode.PARTIAL)
+        uids = buf.load_prompts([[1, 2], [1, 3], [1, 4]])
+        buf.mark_running(uids)
+        buf.record_tokens(uids[0], [5], [-1.0], 0)
+        buf.scavenge(uids[0])                # harvested, then resumed
+        buf.mark_running([uids[0]])
+        time.sleep(0.005)
+        for u in uids:
+            buf.mark_done(u, "length")
+        time.sleep(0.005)
+        buf.consume(uids[:2])
+        facts = chip_smoke.trace_facts(rec, rec.take())
+    finally:
+        trace.disable()
+    assert facts["compiles"] >= 1 and facts["compile_s"] > 0
+    assert facts["compiles_by_span"]["trainer.step"] >= 1
+    n, whole, own = facts["span_s"]["trainer.update"]
+    n_step, step, step_own = facts["span_s"]["trainer.step"]
+    assert n == n_step == 1 and step == step_own
+    assert whole >= step and own == pytest.approx(whole - step)
+    traj = facts["trajectories"]
+    assert traj["consumed"] == 2 and traj["harvested"] == 1
+    assert traj["run_p90_s"] >= 0.005
+    assert traj["done_wait_p90_s"] >= 0.005
+
+
 def test_kernel_phase_at_tiny_width():
     """The chip's kernel phase in interpret mode: every kernel agrees
     with its oracle, and no compiled program holds a Mosaic call."""
