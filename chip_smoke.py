@@ -11,11 +11,14 @@ remat on) and runs at least two RL updates in partial mode under the
 paged prefix sharing run as well.  It checks that every update's loss is
 finite, that the trainer stepped at least twice, that the first update's
 teacher-forced logprobs agree with those the engine recorded while
-sampling, that tokens were generated and that the page pool holds no
-leaked pages.
+sampling, that tokens were generated, that the page pool holds no
+leaked pages and that the decode program aliases the donated pool (its
+``memory_analysis`` is printed with the phase's facts).
 
 Kernel phase: the five Pallas kernels, compiled for the chip, against
-their pure-jnp oracles at Qwen3-0.6B head widths and vocab.
+their pure-jnp oracles at Qwen3-0.6B head widths, depth and vocab; the
+paged decode attention through ``ops.paged_attention``, the entry point
+the decode program calls.
 
 Replica phase: the session's replica factory puts greedy SlotEngine
 replica i of an EngineGroup on local device i.  Each uid's token stream
@@ -152,6 +155,9 @@ def session_phase(model_cfg) -> dict:
           f"engine logprobs disagree with the trainer's: {first}")
     step_s = sorted(dt for running, dt in session.metrics.intervals
                     if running > 0)
+    decode = decode_program_memory(eng)
+    check(decode["alias_bytes"] >= decode["pool_bytes"],
+          f"the decode program does not alias the page pool: {decode}")
     return {"phase": "session", "model": model_cfg.name,
             "build_s": built, "run_s": ran, "updates": len(history),
             "losses": losses, "first_update": first,
@@ -160,7 +166,28 @@ def session_phase(model_cfg) -> dict:
             "engine_step_s_median": step_s[len(step_s) // 2],
             "shared_prefills": st["shared_prefills"],
             "resumed_without_prefill": st["resumed_without_prefill"],
-            "harvests": out["rollout_metrics"]["harvests"]}
+            "harvests": out["rollout_metrics"]["harvests"],
+            "decode_program": decode}
+
+
+def decode_program_memory(eng) -> dict:
+    """``memory_analysis`` of the engine's decode program at its widest
+    block table: the bytes it aliases (the donated pool and scales) and
+    its temporaries, beside the pool's own bytes."""
+    import jax
+    import jax.numpy as jnp
+    nb, B = eng._pages_per_seq, eng.capacity
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+    m = eng._decode_program(nb).lower(
+        eng.params_fn(), i32(B), eng.cache, eng.kv_scales, i32(B, nb),
+        i32(B), eng._key).compile().memory_analysis()
+    return {"width": nb,
+            "pool_bytes": sum(a.nbytes for a in jax.tree.leaves(
+                (eng.cache, eng.kv_scales))),
+            "alias_bytes": m.alias_size_in_bytes,
+            "temp_bytes": m.temp_size_in_bytes}
 
 
 def kernel_phase(model_cfg, slots: int = 8, seq: int = 512,
@@ -186,14 +213,21 @@ def kernel_phase(model_cfg, slots: int = 8, seq: int = 512,
 
     nb = seq // page
     n_pages = slots * nb + 1
+    L = model_cfg.num_layers
     q = normal(slots, H, D)
-    kv_len = jax.random.randint(next(keys), (slots,), 1, seq + 1)
+    kv_len = jax.random.randint(next(keys), (slots,), 1, seq)
     bt = jax.random.permutation(next(keys), n_pages)[:slots * nb].reshape(
         slots, nb).astype(jnp.int32)
-    k_pages, v_pages = normal(n_pages, page, Kh, D), normal(n_pages, page,
-                                                            Kh, D)
-    k8, k_scale = ref.quantize_pages_ref(k_pages)
-    v8, v_scale = ref.quantize_pages_ref(v_pages)
+    # the engine's layered pool, read at its last layer, with the new
+    # token's rows
+    pool = (L, n_pages, page, Kh, D)
+    k_pages, v_pages = normal(*pool), normal(*pool)
+    layer = jnp.int32(L - 1)
+    k_new, v_new = normal(slots, Kh, D), normal(slots, Kh, D)
+    k8, k_scale = ref.quantize_pages_ref(k_pages.reshape(-1, *pool[2:]))
+    v8, v_scale = ref.quantize_pages_ref(v_pages.reshape(-1, *pool[2:]))
+    k8, v8 = k8.reshape(pool), v8.reshape(pool)
+    k_scale, v_scale = k_scale.reshape(pool[:2]), v_scale.reshape(pool[:2])
     fq = normal(2, seq, H, D)
     fk, fv = normal(2, seq, Kh, D), normal(2, seq, Kh, D)
     # row 0 packs two prompts and a pad tail; row 1 is one prompt
@@ -202,18 +236,26 @@ def kernel_phase(model_cfg, slots: int = 8, seq: int = 512,
     seg[1] = 0
     x, w = normal(slots, Dm), normal(Dm, model_cfg.vocab_size,
                                      scale=Dm ** -0.5)
+
+    def with_scales(attend):
+        return lambda q, k, v, ks, vs, *rest: attend(
+            q, k, v, *rest, k_scales=ks, v_scales=vs)
+
+    # the paged attention is checked through the entry point the decode
+    # program calls (the kernel on the chip at 128-lane head widths)
     cases = {
         "ragged_decode_attention": (
             ops.ragged_decode_attention, ref.ragged_decode_attention_ref,
             (q, normal(slots, seq, Kh, D), normal(slots, seq, Kh, D),
              kv_len), 2e-2),
         "paged_decode_attention": (
-            ops.paged_decode_attention, ref.paged_decode_attention_ref,
-            (q, k_pages, v_pages, bt, kv_len), 2e-2),
+            jax.jit(ops.paged_attention), ref.paged_decode_attention_ref,
+            (q, k_pages, v_pages, bt, kv_len, layer, k_new, v_new), 2e-2),
         "paged_decode_attention_int8": (
-            ops.paged_decode_attention_int8,
-            ref.paged_decode_attention_int8_ref,
-            (q, k8, v8, k_scale, v_scale, bt, kv_len), 2e-2),
+            jax.jit(with_scales(ops.paged_attention)),
+            with_scales(ref.paged_decode_attention_ref),
+            (q, k8, v8, k_scale, v_scale, bt, kv_len, layer, k_new, v_new),
+            2e-2),
         "flash_attention": (
             ops.flash_attention,
             lambda q, k, v, s: ref.flash_attention_ref(q, k, v, seg_ids=s),

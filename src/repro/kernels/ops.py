@@ -2,8 +2,10 @@
 
 Each kernel runs compiled on a TPU and in Pallas interpret mode when
 JAX's default backend is the CPU (:func:`repro.kernels.default_interpret`,
-resolved at trace time).  The model layers call the pure-jnp paths; the
-tests compare these wrappers with the oracles in ``ref.py``.
+resolved at trace time); the tests compare these wrappers with the
+oracles in ``ref.py``.  ``paged_attention`` is the paged decode's
+attention as the model calls it (not jitted: it runs inside the decode
+program; the tests and ``chip_smoke.py`` jit it themselves).
 """
 from __future__ import annotations
 
@@ -11,6 +13,8 @@ import functools
 
 import jax
 
+from repro import kernels
+from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.ragged_decode_attention import (
     fused_sample as _fused_sample)
@@ -27,19 +31,25 @@ def ragged_decode_attention(q, k_cache, v_cache, kv_len, block_k: int = 128,
                    softcap=softcap)
 
 
-@functools.partial(jax.jit, static_argnames=("softcap",))
-def paged_decode_attention(q, k_pages, v_pages, block_tables, kv_len,
-                           softcap: float = 0.0):
-    return _paged(q, k_pages, v_pages, block_tables, kv_len,
-                  softcap=softcap)
-
-
-@functools.partial(jax.jit, static_argnames=("softcap",))
-def paged_decode_attention_int8(q, k_pages, v_pages, k_scales, v_scales,
-                                block_tables, kv_len, softcap: float = 0.0):
-    """Paged decode over int8-quantized pages with per-page f32 scales."""
-    return _paged(q, k_pages, v_pages, block_tables, kv_len,
-                  softcap=softcap, k_scales=k_scales, v_scales=v_scales)
+def paged_attention(q, k_pages, v_pages, block_tables, kv_len, layer, k_new,
+                    v_new, *, softcap: float = 0.0, k_scales=None,
+                    v_scales=None):
+    """The decode step's attention as the engine serves it: the Pallas
+    kernel, compiled, on a TPU; its jnp reference where the kernel could
+    only be interpreted (the CPU backend, so an engine step there never
+    runs the Pallas interpreter) or cannot compile: a head_dim that is not
+    a multiple of the 128 lanes, whose page copies Mosaic refuses.
+    Chosen at trace time from :func:`repro.kernels.default_interpret` and
+    the pool's shape; arguments as the kernel's
+    (``ragged_decode_attention.paged_decode_attention``), an int8 pool's
+    scales as ``k_scales``/``v_scales``."""
+    if kernels.default_interpret() or k_pages.shape[-1] % 128:
+        return ref.paged_decode_attention_ref(
+            q, k_pages, v_pages, block_tables, kv_len, layer, k_new, v_new,
+            softcap=softcap, k_scales=k_scales, v_scales=v_scales)
+    return _paged(q, k_pages, v_pages, block_tables, kv_len, layer, k_new,
+                  v_new, softcap=softcap, k_scales=k_scales,
+                  v_scales=v_scales, interpret=False)
 
 
 @functools.partial(jax.jit, static_argnames=("block_v", "softcap"))

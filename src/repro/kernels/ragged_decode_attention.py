@@ -2,28 +2,24 @@
 
 One new token per slot attends over a per-slot-length KV cache.  This is
 the kernel the paper's scheduling feeds: length-sorted batches mean
-neighbouring slots share similar ``kv_len``, so the kv-block skip pattern
-(``@pl.when`` on block start < kv_len) is uniform across the grid and the
-engine streams only live cache — the TPU-native payoff of SortedRL's
-sorting (see DESIGN.md §3).
+neighbouring slots share similar ``kv_len``, so the engine streams only
+live cache — the TPU-native payoff of SortedRL's sorting.
 
-Two variants share one kernel body:
+* ``ragged_decode_attention`` — dense ``(B, S, Kh, D)`` cache; grid
+  (B, S // block_k), one (block_k, Kh, D) tile per program, blocks past
+  ``kv_len`` skipped with ``@pl.when``.
+* ``paged_decode_attention`` — the decode step's attention on the
+  engine's layered page pool ``(L, N, page, Kh, D)``, read in place: the
+  layer index, the block tables and a work list of live blocks are
+  scalar-prefetch operands, and the kernel DMAs each live page of the
+  layer straight from HBM, several pages per block and double-buffered
+  across blocks and slots.  No dense per-slot view exists; shared GRPO
+  prefix pages are read by every member that maps them.  The step's new
+  token is not in the pool yet: it joins the kernel's online-softmax
+  partials afterwards (``_with_new_token``), and the engine writes its
+  row into the pool after the layer scan.
 
-* ``ragged_decode_attention`` — dense ``(B, S, Kh, D)`` cache, kv blocks
-  addressed contiguously (grid position == block index);
-* ``paged_decode_attention`` — the cache is a pool of fixed-size pages
-  ``(N, page, Kh, D)`` and each slot owns a *block table* mapping logical
-  kv blocks to physical pages (``repro.core.kv_cache``).  The table is a
-  scalar-prefetch operand, so the BlockSpec index_map dereferences it to
-  DMA exactly the pages a slot maps — shared GRPO prefix pages stream
-  once per slot without ever materialising a dense per-slot cache.
-
-Tiling: grid (B, S // block_k); each program holds the full (H, D) query
-tile in VMEM plus one (block_k, Kh, D) cache tile; flash-decode online
-softmax accumulates in VMEM scratch across the sequential k dimension.
-Per-slot lengths (and the paged variant's block tables) are
-scalar-prefetch operands in SMEM.  D is the lane dimension; for the paged
-variant block_k == page size (16 and 128 both compile for TPU v5e).
+D is the lane dimension; Kh * D rows of a page are the sublanes.
 """
 from __future__ import annotations
 
@@ -33,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,13 +39,11 @@ NEG_INF = -1e30
 
 
 def _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                        *, kblk, nk, kstart, kv_len, softcap: float,
-                        k_scale=None, v_scale=None):
+                        *, kblk, nk, kstart, kv_len, softcap: float):
     """Shared flash-decode body: one (block_k, Kh, D) kv tile starting at
     logical position `kstart`, online-softmax accumulated in VMEM scratch.
     Refs: q (H, D) | k/v (block_k, Kh, D) | o (H, D) |
-    scratch m/l (H, 1) f32, acc (H, D) f32.
-    ``k_scale``/``v_scale``: per-page dequant scalars (int8 KV pages)."""
+    scratch m/l (H, 1) f32, acc (H, D) f32."""
 
     @pl.when(kblk == 0)
     def _init():
@@ -61,10 +56,6 @@ def _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         q = q_ref[...].astype(jnp.float32)            # (H, D)
         k = k_ref[...].astype(jnp.float32)            # (bk, Kh, D)
         v = v_ref[...].astype(jnp.float32)
-        if k_scale is not None:
-            k = k * k_scale
-        if v_scale is not None:
-            v = v * v_scale
         H, D = q.shape
         bk, Kh, _ = k.shape
         G = H // Kh
@@ -97,44 +88,13 @@ def _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 def _kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *, block_k: int, softcap: float):
-    """Dense variant: kv block `kb` sits at cache rows [kb*block_k, ...)."""
+    """kv block `kb` sits at cache rows [kb*block_k, ...)."""
     b = pl.program_id(0)
     kblk = pl.program_id(1)
     _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                         kblk=kblk, nk=pl.num_programs(1),
                         kstart=kblk * block_k, kv_len=kv_len_ref[b],
                         softcap=softcap)
-
-
-def _paged_kernel(bt_ref, kv_len_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page_size: int, softcap: float):
-    """Paged variant: the BlockSpec index_map already dereferenced the
-    block table (scalar prefetch), so k/v refs hold the physical page for
-    logical block `kb`; only the position base differs from dense."""
-    del bt_ref   # consumed by the index_map
-    b = pl.program_id(0)
-    kblk = pl.program_id(1)
-    _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                        kblk=kblk, nk=pl.num_programs(1),
-                        kstart=kblk * page_size, kv_len=kv_len_ref[b],
-                        softcap=softcap)
-
-
-def _paged_kernel_int8(bt_ref, kv_len_ref, ks_ref, vs_ref, q_ref, k_ref,
-                       v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                       page_size: int, softcap: float):
-    """int8-page variant: k/v pages are stored quantized with one f32
-    scale per physical page; the scales ride in as scalar-prefetch
-    operands and are dereferenced through the same block table as the
-    page itself, so dequant happens in-register after the page DMA."""
-    b = pl.program_id(0)
-    kblk = pl.program_id(1)
-    page = bt_ref[b, kblk]
-    _flash_decode_block(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                        kblk=kblk, nk=pl.num_programs(1),
-                        kstart=kblk * page_size, kv_len=kv_len_ref[b],
-                        softcap=softcap,
-                        k_scale=ks_ref[page], v_scale=vs_ref[page])
 
 
 def ragged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -180,87 +140,217 @@ def ragged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     )(kv_len.astype(jnp.int32), q, k_cache, v_cache)
 
 
+def _softcap(s, softcap: float):
+    return jnp.tanh(s / softcap) * softcap if softcap > 0 else s
+
+
+PAGES_PER_BLOCK = 16    # 256 tokens a block at the engine's 16-token pages
+
+
+def _paged_kernel(layer_ref, bt_ref, kv_len_ref, slot_ref, blk_ref,
+                  count_ref, *refs, ppb: int, nbp: int, quant: bool,
+                  softcap: float):
+    """Flash-decode partials over the live blocks of one layer's pages.
+
+    Scalar prefetch: the layer, the padded block table flattened
+    (B * nbp,), kv_len (B,), the work list of (slot, block) pairs, its
+    length, and for int8 pools each (slot, page) scale of this layer.
+    Work item t covers logical pages [blk*ppb, (blk+1)*ppb) of its slot;
+    a page wholly past kv_len is not copied.  While item t is computed the
+    pages of item t+1 are in flight into the other buffer, so the copies
+    of one slot's last block and the next slot's first overlap too.
+
+    Rows of a block are (token, kv head) pairs: the scores of every query
+    head against every row come out of one matmul and the rows of other
+    kv heads are masked (``head_ref``), which keeps the kernel to 2-D
+    matmuls on the (rows, D) tile at any GQA ratio.  The outputs hold
+    each slot's running max m, sum l and unnormalised acc (B, H, ...)."""
+    if quant:
+        ks_ref, vs_ref, *refs = refs
+    (q_ref, head_ref, tok_ref, k_hbm, v_hbm, m_ref, l_ref, acc_ref,
+     kbuf, vbuf, sems) = refs
+    _, page, kh, d = kbuf.shape[1:]
+    rows, bk = ppb * page * kh, ppb * page
+    layer, count = layer_ref[0], count_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    # pages left uncopied must hold finite values (their p is 0)
+    kbuf[...] = jnp.zeros_like(kbuf)
+    vbuf[...] = jnp.zeros_like(vbuf)
+
+    def page_copies(t, buf):
+        b, i = slot_ref[t], blk_ref[t]
+        for j in range(ppb):
+            p = bt_ref[b * nbp + i * ppb + j]
+            yield ((i * ppb + j) * page < kv_len_ref[b],
+                   pltpu.make_async_copy(k_hbm.at[layer, p], kbuf.at[buf, j],
+                                         sems.at[0, buf]),
+                   pltpu.make_async_copy(v_hbm.at[layer, p], vbuf.at[buf, j],
+                                         sems.at[1, buf]))
+
+    def start(t, buf):
+        for live, ck, cv in page_copies(t, buf):
+            @pl.when(live)
+            def _():
+                ck.start()
+                cv.start()
+
+    def wait(t, buf):
+        for live, ck, cv in page_copies(t, buf):
+            @pl.when(live)
+            def _():
+                ck.wait()
+                cv.wait()
+
+    def load(buf_ref, sc_ref, buf, col):
+        if sc_ref is None:
+            x = buf_ref[buf].astype(jnp.float32).reshape(rows, d)
+        else:
+            x = jnp.concatenate(
+                [(buf_ref[buf, j].astype(jnp.float32)
+                  * sc_ref[col + j]).reshape(page * kh, d)
+                 for j in range(ppb)], axis=0)
+        return x.astype(q_ref.dtype)
+
+    @pl.when(count > 0)
+    def _():
+        start(0, 0)
+
+    def body(t, carry):
+        buf = t % 2
+
+        @pl.when(t + 1 < count)
+        def _():
+            start(t + 1, 1 - buf)
+
+        wait(t, buf)
+        b, i = slot_ref[t], blk_ref[t]
+        col = b * nbp + i * ppb
+        k = load(kbuf, ks_ref if quant else None, buf, col)   # (rows, D)
+        v = load(vbuf, vs_ref if quant else None, buf, col)
+        s = _softcap(jax.lax.dot_general(
+            q_ref[b], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32), softcap)
+        valid = (head_ref[...] > 0) & (i * bk + tok_ref[...] < kv_len_ref[b])
+        s = jnp.where(valid, s, NEG_INF)                      # (H, rows)
+        m_prev = m_ref[b]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[b] = l_ref[b] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[b] = acc_ref[b] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[b] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, count, body, 0)
+
+
+def _with_new_token(qs, m, l, acc, k_new, v_new, softcap: float):
+    """Fold the new token's own key and value into the pool partials:
+    the merge of two online-softmax states, the second of one row."""
+    B, H, D = qs.shape
+    Kh = k_new.shape[1]
+    G = H // Kh
+    s = jnp.einsum("bkgd,bkd->bkg", qs.reshape(B, Kh, G, D),
+                   k_new.astype(qs.dtype),
+                   preferred_element_type=jnp.float32).reshape(B, H)
+    s = _softcap(s, softcap)
+    m, l = m[..., 0], l[..., 0]
+    top = jnp.maximum(m, s)
+    a, c = jnp.exp(m - top), jnp.exp(s - top)
+    vn = jnp.repeat(v_new.astype(jnp.float32), G, axis=1)
+    return ((acc * a[..., None] + c[..., None] * vn)
+            / (l * a + c)[..., None])
+
+
 def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, block_tables: jnp.ndarray,
-                           kv_len: jnp.ndarray, *, softcap: float = 0.0,
-                           k_scales: jnp.ndarray = None,
-                           v_scales: jnp.ndarray = None,
+                           kv_len: jnp.ndarray, layer, k_new: jnp.ndarray,
+                           v_new: jnp.ndarray, *, softcap: float = 0.0,
+                           k_scales: Optional[jnp.ndarray] = None,
+                           v_scales: Optional[jnp.ndarray] = None,
                            interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Decode attention over a paged KV pool.
+    """One decode step's attention for one layer, on the page pool in place.
 
-    q: (B, H, D); k/v_pages: (N, page, Kh, D) physical page pool;
-    block_tables: (B, nb) i32 — logical kv block j of slot b lives in
-    physical page ``block_tables[b, j]`` (pad unused entries with any
-    valid page id; rows past ``kv_len`` are masked); kv_len: (B,) valid
-    lengths.  Returns (B, H, D).
+    q: (B, H, D); k/v_pages: (L, N, page, Kh, D) the layered pool;
+    block_tables: (B, nb) i32, logical page j of slot b in physical page
+    ``block_tables[b, j]`` (padding: any valid page, the engine's garbage
+    page); kv_len: (B,) rows already in the pool; layer: scalar index into
+    the pool's first axis; k_new/v_new: (B, Kh, D) the new token's key and
+    value, at position kv_len.  Returns (B, H, D): attention over rows
+    [0, kv_len) of the layer's pages plus the new token.  A slot with
+    kv_len 0 (an inactive one) attends to its new token alone.
 
-    The block table and kv_len ride in as scalar-prefetch operands so the
-    k/v index_maps can dereference the table — each grid step DMAs one
-    physical page, which is how a GRPO group's shared prefix pages are
-    read by every member without a dense per-slot copy.
+    ``k_scales``/``v_scales``: (L, N) f32 per-(layer, page) dequant scales
+    of an int8 pool; this layer's scale of each mapped page rides in
+    scalar memory and dequantizes the page in VMEM after its copy.  The
+    matmuls run in the dtype of ``k_new`` with f32 accumulation.
 
-    ``k_scales``/``v_scales``: (N,) f32 per-page dequant scales for int8
-    page pools (``kv_quant="int8"`` engines).  They join the scalar
-    prefetch so the kernel dequantises each page in-register right after
-    its DMA — the pool stays int8 in HBM, halving (vs bf16; quartering vs
-    f32) the decode's memory traffic and doubling effective capacity.
-
+    The pool is handed whole (``pl.ANY``) with the layer index, so a call
+    inside the model's layer scan slices nothing out of it.
     ``interpret=None`` interprets only on the CPU backend.
     """
     B, H, D = q.shape
-    page, Kh = k_pages.shape[1], k_pages.shape[2]
+    _, _, page, Kh, _ = k_pages.shape
     nb = block_tables.shape[1]
     assert block_tables.shape[0] == B and kv_len.shape == (B,)
     quant = k_scales is not None
     assert quant == (v_scales is not None)
+    ppb = min(PAGES_PER_BLOCK, nb)
+    nbp = -(-nb // ppb) * ppb
+    nblk_max, rows, bk = nbp // ppb, ppb * page * Kh, ppb * page
+    cdt = k_new.dtype
+    bt = jnp.pad(block_tables.astype(jnp.int32), ((0, 0), (0, nbp - nb)))
+    kv_len = kv_len.astype(jnp.int32)
+    # the work list: every live block of every slot, slot-major
+    nblk = (kv_len + bk - 1) // bk
+    ends = jnp.cumsum(nblk)
+    t = jnp.arange(B * nblk_max, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(ends[None, :] <= t[:, None], axis=1),
+                       B - 1).astype(jnp.int32)
+    blk = (t - (ends - nblk)[slot]).astype(jnp.int32)
+    scalars = [jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+               bt.reshape(-1), kv_len, slot, blk, ends[-1:]]
     if quant:
-        kernel = functools.partial(_paged_kernel_int8, page_size=page,
-                                   softcap=softcap)
-        nsp = 4                      # block_tables, kv_len, k/v_scales
-        scalar_ops = (block_tables.astype(jnp.int32),
-                      kv_len.astype(jnp.int32),
-                      k_scales.astype(jnp.float32),
-                      v_scales.astype(jnp.float32))
+        scalars += [jnp.take(sc[layer], bt.reshape(-1)).astype(jnp.float32)
+                    for sc in (k_scales, v_scales)]
+    # row r of a block is (token r // Kh, kv head r % Kh)
+    r = np.arange(rows)
+    head_ok = jnp.asarray((r[None, :] % Kh) == (np.arange(H)[:, None]
+                                                // (H // Kh)), jnp.int32)
+    tok = jnp.asarray((r // Kh)[None, :], jnp.int32)
+    qs = (q.astype(jnp.float32) / math.sqrt(D)).astype(cdt)
 
-        def q_map(b, kb, bt, kl, ks, vs):
-            return (b, 0, 0)
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda g, *_: (0,) * len(shape))
 
-        def kv_map(b, kb, bt, kl, ks, vs):
-            return (bt[b, kb], 0, 0, 0)
-    else:
-        kernel = functools.partial(_paged_kernel, page_size=page,
-                                   softcap=softcap)
-        nsp = 2                      # block_tables, kv_len
-        scalar_ops = (block_tables.astype(jnp.int32),
-                      kv_len.astype(jnp.int32))
-
-        def q_map(b, kb, bt, kl):
-            return (b, 0, 0)
-
-        def kv_map(b, kb, bt, kl):
-            return (bt[b, kb], 0, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=nsp,
-        grid=(B, nb),
-        in_specs=[
-            pl.BlockSpec((None, H, D), q_map),
-            pl.BlockSpec((None, page, Kh, D), kv_map),
-            pl.BlockSpec((None, page, Kh, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((None, H, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
+    kernel = functools.partial(_paged_kernel, ppb=ppb, nbp=nbp, quant=quant,
+                               softcap=softcap)
+    m, l, acc = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(1,),
+            in_specs=[whole((B, H, D)), whole((H, rows)), whole((1, rows)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[whole((B, H, 1)), whole((B, H, 1)),
+                       whole((B, H, D))],
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, page, Kh, D), k_pages.dtype),
+                pltpu.VMEM((2, ppb, page, Kh, D), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, D), jnp.float32)],
         interpret=default_interpret() if interpret is None else interpret,
         name="paged_decode_attention",
-    )(*scalar_ops, q, k_pages, v_pages)
+    )(*scalars, qs, head_ok, tok, k_pages, v_pages)
+    return _with_new_token(qs, m, l, acc, k_new, v_new,
+                           softcap).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -39,6 +39,11 @@ class Model:
     # segment-offset tables (batch adds "seg_ids"/"positions").  None for
     # families without segment-masked attention support.
     prefill_packed: Any = None     # (params, batch, cache) -> (logits, cache)
+    # decode on a layered page pool read in place (families whose cache is
+    # plain {k, v} with full attention); None for the others.
+    # (params, token, pages, scales, block_tables, kv_len, return_hidden)
+    #   -> (logits or hidden, (k_new, v_new))
+    decode_paged: Any = None
 
 
 def _moe_mlp_fn(cfg: ModelConfig, ep_mesh=None, data_axes=("data",)):
@@ -87,6 +92,14 @@ def build_model(cfg: ModelConfig, ep_mesh=None, data_axes=("data",)) -> Model:
             return TF.decode(params, cfg, token, cache, kv_len, mlp_fn=mlp_fn,
                              return_hidden=kw.get("return_hidden", False))
 
+        decode_paged = None
+        if TF.pattern_len(cfg) == 1 and not cfg.attn.sliding_window:
+            def decode_paged(params, token, pages, scales, block_tables,
+                             kv_len, return_hidden=False):
+                return TF.decode_step_paged(
+                    params, cfg, token, pages, scales, block_tables, kv_len,
+                    mlp_fn=mlp_fn, return_hidden=return_hidden)
+
         prefill_packed = None
         if fam != "vlm":
             # vlm prepends stub patch rows per prompt — incompatible with
@@ -101,7 +114,8 @@ def build_model(cfg: ModelConfig, ep_mesh=None, data_axes=("data",)) -> Model:
                      decode_step, padding_side="right",
                      prefill_extra=(cfg.num_stub_positions
                                     if fam == "vlm" else 0),
-                     prefill_packed=prefill_packed)
+                     prefill_packed=prefill_packed,
+                     decode_paged=decode_paged)
 
     if fam == "hybrid":
         def forward(params, batch):

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import logical_constraint
+from repro.kernels import ops
 from repro.models import layers as L
 
 Params = Dict[str, Any]
@@ -210,6 +211,39 @@ def _write_token(cache_layer: jnp.ndarray, new: jnp.ndarray,
 # Decode step: one token, scan over layers
 # ---------------------------------------------------------------------------
 
+def _decode_layer(group: Params, cfg: ModelConfig, h: jnp.ndarray,
+                  kv_len: jnp.ndarray, attend: Callable,
+                  mlp_fn: Optional[Callable]):
+    """One layer of a one-token decode step.  ``attend(q, k, v)`` gets
+    the token's q (B, H, D) and k, v (B, Kh, D) and returns the attention
+    output (B, H, D).  Returns (h, k, v)."""
+    hn = L.norm(h, group["ln1"], cfg.norm_type, cfg.norm_eps)
+    q, k, v = L.qkv_project(group["attn"], cfg, hn, kv_len[:, None])
+    k, v = k[:, 0], v[:, 0]
+    h = h + L.attn_output(group["attn"], attend(q[:, 0], k, v)[:, None])
+    hn = L.norm(h, group["ln2"], cfg.norm_type, cfg.norm_eps)
+    y, _ = _apply_mlp(group, cfg, hn, mlp_fn)
+    return h + y, k, v
+
+
+def _decode_input(params: Params, cfg: ModelConfig, token, kv_len, embeds):
+    if embeds is None:
+        x = embed_tokens(params, cfg, token[:, None])
+    else:
+        x = embeds[:, None] if embeds.ndim == 2 else embeds
+    if cfg.pos_embedding == "learned":
+        x = x + params["pos_embed"][kv_len][:, None].astype(x.dtype)
+    return x
+
+
+def _decode_output(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+                   return_hidden: bool) -> jnp.ndarray:
+    if return_hidden:
+        return L.norm(x[:, 0], params["final_norm"], cfg.norm_type,
+                      cfg.norm_eps)
+    return lm_logits(params, cfg, x[:, 0])
+
+
 def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
                 cache: Dict[str, jnp.ndarray], kv_len: jnp.ndarray,
                 mlp_fn: Optional[Callable] = None,
@@ -223,43 +257,71 @@ def decode_step(params: Params, cfg: ModelConfig, token: jnp.ndarray,
     instead of logits — the fused-sampling decode path computes the LM
     head blockwise in the same pass as max/lse, so the full (B, V)
     logits round-trip never materialises (see rollout/engine.py)."""
-    if embeds is None:
-        x = embed_tokens(params, cfg, token[:, None])
-    else:
-        x = embeds[:, None] if embeds.ndim == 2 else embeds
-    if cfg.pos_embedding == "learned":
-        x = x + params["pos_embed"][kv_len][:, None].astype(x.dtype)
+    x = _decode_input(params, cfg, token, kv_len, embeds)
     if pattern_len(cfg) == 2:
         raise ValueError("use decode_step_pattern for local/global archs")
+
     # write the new token's k/v first, then attend over the cache.
     # Pass cache slices as scan xs; collect per-layer new kv as ys.
-    if True:
-        def body(h, xs):
-            group, kc, vc = xs
-            hn = L.norm(h, group["ln1"], cfg.norm_type, cfg.norm_eps)
-            q, k, v = L.qkv_project(group["attn"], cfg, hn, kv_len[:, None])
-            kc = _write_token(kc[None], k[None, :, 0], kv_len)[0]
-            vc = _write_token(vc[None], v[None, :, 0], kv_len)[0]
-            o = L.decode_attention(q[:, 0], kc, vc, kv_len + 1,
-                                   softcap=cfg.attn.attn_softcap,
-                                   window=cfg.attn.sliding_window)
-            h = h + L.attn_output(group["attn"], o[:, None])
-            hn = L.norm(h, group["ln2"], cfg.norm_type, cfg.norm_eps)
-            y, _ = _apply_mlp(group, cfg, hn, mlp_fn)
-            h = h + y
-            return h, (k[:, 0], v[:, 0])
+    def body(h, xs):
+        group, kc, vc = xs
 
-        x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"],
-                                                   cache["k"], cache["v"]))
-        cache = dict(cache)
-        cache["k"] = _write_token(cache["k"], k_new, kv_len)
-        cache["v"] = _write_token(cache["v"], v_new, kv_len)
-        if return_hidden:
-            hidden = L.norm(x[:, 0], params["final_norm"], cfg.norm_type,
-                            cfg.norm_eps)
-            return hidden, cache
-        logits = lm_logits(params, cfg, x[:, 0])
-        return logits, cache
+        def attend(q, k, v):
+            kc_ = _write_token(kc[None], k[None], kv_len)[0]
+            vc_ = _write_token(vc[None], v[None], kv_len)[0]
+            return L.decode_attention(q, kc_, vc_, kv_len + 1,
+                                      softcap=cfg.attn.attn_softcap,
+                                      window=cfg.attn.sliding_window)
+
+        h, k, v = _decode_layer(group, cfg, h, kv_len, attend, mlp_fn)
+        return h, (k, v)
+
+    x, (k_new, v_new) = jax.lax.scan(body, x, (params["layers"],
+                                               cache["k"], cache["v"]))
+    cache = dict(cache)
+    cache["k"] = _write_token(cache["k"], k_new, kv_len)
+    cache["v"] = _write_token(cache["v"], v_new, kv_len)
+    return _decode_output(params, cfg, x, return_hidden), cache
+
+
+def decode_step_paged(params: Params, cfg: ModelConfig, token: jnp.ndarray,
+                      pages: Dict[str, jnp.ndarray],
+                      scales: Dict[str, jnp.ndarray],
+                      block_tables: jnp.ndarray, kv_len: jnp.ndarray,
+                      mlp_fn: Optional[Callable] = None,
+                      return_hidden: bool = False):
+    """One decode step on a page pool, read in place.
+
+    pages: {"k", "v"} (L, N, page, Kh, D); scales: {} or an int8 pool's
+    {"k", "v"} (L, N) f32; block_tables: (B, nb) physical pages of each
+    slot; kv_len: (B,) rows in the pool, the new token's position.  Each
+    layer's attention reads that layer's pages through the block tables
+    (``kernels.ops.paged_attention``) and folds in the new token, which is
+    not written here.  Returns (logits (B, V), or the final-normed hidden
+    state (B, d) with ``return_hidden``, and (k_new, v_new), each
+    (L, B, Kh, D) in the compute dtype): the caller writes those rows at
+    ``kv_len``."""
+    assert pattern_len(cfg) == 1 and not cfg.attn.sliding_window, \
+        "paged decode: every layer attends over its whole context"
+    x = _decode_input(params, cfg, token, kv_len, None)
+
+    def body(h, xs):
+        group, layer = xs
+
+        def attend(q, k, v):
+            with jax.named_scope("attend"):
+                return ops.paged_attention(
+                    q, pages["k"], pages["v"], block_tables, kv_len, layer,
+                    k.astype(cfg.compute_dtype), v.astype(cfg.compute_dtype),
+                    softcap=cfg.attn.attn_softcap, k_scales=scales.get("k"),
+                    v_scales=scales.get("v"))
+
+        h, k, v = _decode_layer(group, cfg, h, kv_len, attend, mlp_fn)
+        return h, (k.astype(cfg.compute_dtype), v.astype(cfg.compute_dtype))
+
+    x, kv_new = jax.lax.scan(body, x, (params["layers"],
+                                       jnp.arange(cfg.num_layers)))
+    return _decode_output(params, cfg, x, return_hidden), kv_new
 
 
 def decode_step_pattern(params: Params, cfg: ModelConfig, token: jnp.ndarray,

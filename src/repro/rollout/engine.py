@@ -26,16 +26,12 @@ page table (:mod:`repro.core.kv_cache`).  This buys what a dense
   resident, so a scavenged ``partial``-mode entry (or the prompt of an
   on-policy re-roll) resumes by remapping pages instead of re-running
   prefill.
-The decode step materialises a dense per-slot view by gathering pages
-through the block tables (bucketed to a power-of-two table width), runs
-the model's unchanged ``decode_step`` on it, and scatters each slot's
-written page back.  The TPU-ready decode attention that reads pages
-*without* the gather is
-``kernels/ragged_decode_attention.paged_decode_attention`` (block tables
-as scalar-prefetch operands); it is validated cell-for-cell against the
-same gather view (``kernels/ref.gather_pages``) and is the drop-in for
-the model's attention layer when deploying on hardware — the engine's
-gather path stays as the CPU/test oracle.
+The decode step works on the pool in place: each layer's attention reads
+that layer's live pages through the block tables (bucketed to a
+power-of-two table width) — the Pallas ``paged_decode_attention`` on a
+TPU, its jnp reference on the CPU — and the new token's K/V rows go
+straight into their pages after the layer scan.  The pool (and an int8
+pool's scales) is donated to the decode program, so no step copies it.
 
 Families with exotic cache layouts (ssm/hybrid state, local/global ring
 buffers, cross-attention) fall back to the dense layout (``paged=False``).
@@ -109,6 +105,22 @@ def cache_put(cache: Dict[str, jnp.ndarray], sub: Dict[str, jnp.ndarray],
     return out
 
 
+def _put_slots(pool: jnp.ndarray, rows: jnp.ndarray,
+               *starts: jnp.ndarray) -> jnp.ndarray:
+    """``rows[:, b]`` of each slot b into ``pool[:, starts[0][b],
+    starts[1][b], ...]``, one ``dynamic_update_slice`` a slot.  Each stays
+    in place in the pool's own layout; a scatter there would have XLA lay
+    out a narrow-head pool (64 lanes of head_dim, whose device layout puts
+    the page axis minor) afresh and back, two copies of the pool a step.
+    Slots sharing a page (the garbage page) write in slot order."""
+    for b in range(rows.shape[1]):
+        at = [0] * pool.ndim
+        for ax, s in enumerate(starts, start=1):
+            at[ax] = s[b]
+        pool = jax.lax.dynamic_update_slice(pool, rows[:, b:b + 1], at)
+    return pool
+
+
 def _named(scope: str, fn: Callable) -> Callable:
     """``fn`` with its ops under ``jax.named_scope(scope)``; a jitted
     program keeps ``fn``'s name."""
@@ -120,8 +132,9 @@ def _named(scope: str, fn: Callable) -> Callable:
 
 
 def supports_paging(model: Model) -> bool:
-    """Paged layout needs right padding and a plain {k, v} cache."""
-    if model.padding_side != "right":
+    """Paged layout needs right padding, a plain {k, v} cache and a decode
+    step that reads a page pool."""
+    if model.padding_side != "right" or model.decode_paged is None:
         return False
     shapes = jax.eval_shape(lambda: model.init_cache(1, 1))
     return set(shapes) == {"k", "v"}
@@ -539,104 +552,80 @@ class SlotEngine:
 
     def _paged_decode_fn(self, params, token, cache, scales, bt, kv_len,
                          key):
-        """One decode step over the page pool.
+        """One decode step over the page pool, in place.
 
-        Gathers a dense per-slot view through the block tables (the CPU
-        analogue of the paged Pallas kernel's block-table reads), runs the
-        model's decode step on it, then scatters each slot's written page
-        back.  Host-side COW (``prepare_step``) guarantees write pages are
-        exclusively owned, so the scatter indices never collide except on
-        the shared garbage page of inactive slots.
+        The model's paged decode reads each layer's pages through the
+        block tables and attends to the new token too; the new token's
+        K/V rows are then written at ``kv_len`` of each slot's write page
+        (``_write_kv``).  Host-side COW (``prepare_step``) guarantees write
+        pages are exclusively owned, so the writes never collide except on
+        the shared garbage page of inactive slots.  ``cache`` and
+        ``scales`` are donated by ``_paged_decode``: the returned pool
+        replaces them.
 
-        int8 pools dequantize on gather (per-page scales — the CPU
-        analogue of the scalar-prefetched scales in
-        ``kernels.ops.paged_decode_attention_int8``) and requantize the
-        written page on scatter with a monotone-nondecreasing scale, so a
-        page whose amax did not grow round-trips its old cells exactly.
-
-        Its ops sit under the named scopes ``decode/gather_kv``,
-        ``decode/model``, ``decode/sample`` and ``decode/write_page``.
+        Its ops sit under the named scopes ``decode/model`` (the layer
+        scan, with ``attend`` around each layer's attention),
+        ``decode/sample`` and ``decode/write_kv``.
         """
+        fused = self.fused_sampling and self.temperature == 0
         with jax.named_scope("decode"):
-            return self._paged_decode_body(params, token, cache, scales, bt,
-                                           kv_len, key)
-
-    def _paged_decode_body(self, params, token, cache, scales, bt, kv_len,
-                           key):
-        P = self.page_size
-        B, nb = bt.shape
-        quant = self.kv_quant == "int8"
-
-        def gather(pages, sc):
-            g = jnp.take(pages, bt.reshape(-1), axis=1)
-            g = g.reshape(pages.shape[0], B, nb, P, *pages.shape[3:])
-            if quant:
-                s = jnp.take(sc, bt.reshape(-1), axis=1)
-                g = g.astype(jnp.float32) * s.reshape(
-                    pages.shape[0], B, nb)[..., None, None, None]
-                g = g.astype(self.model.cfg.compute_dtype)
-            return g.reshape(pages.shape[0], B, nb * P, *pages.shape[3:])
-
-        with jax.named_scope("gather_kv"):
-            view = {"k": gather(cache["k"], scales.get("k")),
-                    "v": gather(cache["v"], scales.get("v"))}
-        if self.fused_sampling and self.temperature == 0:
             with jax.named_scope("model"):
-                hidden, view = self.model.decode_step(
-                    params, token, view, kv_len, return_hidden=True)
+                out, (k_new, v_new) = self.model.decode_paged(
+                    params, token, cache, scales, bt, kv_len,
+                    return_hidden=fused)
             with jax.named_scope("sample"):
-                sampled, lp = self._fused_greedy(params, hidden)
-        else:
-            with jax.named_scope("model"):
-                logits, view = self.model.decode_step(params, token, view,
-                                                      kv_len)
-            with jax.named_scope("sample"):
-                sampled, lp = self._sample(logits, key)
-        with jax.named_scope("write_page"):
-            cache, scales = self._write_page(view, cache, scales, bt, kv_len)
+                sampled, lp = (self._fused_greedy(params, out) if fused
+                               else self._sample(out, key))
+            with jax.named_scope("write_kv"):
+                cache, scales = self._write_kv(cache, scales, k_new, v_new,
+                                               bt, kv_len)
         return sampled, lp, cache, scales
 
-    def _write_page(self, view, cache, scales, bt, kv_len):
-        """Each slot's page holding its newest token, from the dense view
-        back to its physical page (int8: requantized, scales monotone)."""
+    def _write_kv(self, cache, scales, k_new, v_new, bt, kv_len):
+        """The new token's rows (L, B, Kh, D) into row ``kv_len % P`` of
+        each slot's page ``bt[b, kv_len // P]``.  An int8 page is
+        dequantized, given its row and requantized with a monotone scale
+        (``max(old, amax / 127)``), so a page whose amax did not grow
+        keeps its old cells exactly."""
         P = self.page_size
-        blk = kv_len // P
-
-        def take_page(x, b):                    # x: (L, S, Kh, D) one slot
-            return jax.lax.dynamic_slice_in_dim(x, b * P, P, axis=1)
-
-        k_new = jax.vmap(take_page, in_axes=(1, 0), out_axes=1)(view["k"], blk)
-        v_new = jax.vmap(take_page, in_axes=(1, 0), out_axes=1)(view["v"], blk)
-        phys = jnp.take_along_axis(bt, blk[:, None], axis=1)[:, 0]
-        if self.kv_quant == "int8":
-            for name, new in (("k", k_new), ("v", v_new)):
-                new = new.astype(jnp.float32)
-                amax = jnp.max(jnp.abs(new), axis=(2, 3, 4))  # (L, B)
-                old = scales[name][:, phys]
-                s = jnp.maximum(old, amax / 127.0)  # monotone: old cells exact
-                q = jnp.clip(jnp.round(new / s[:, :, None, None, None]),
-                             -127, 127).astype(jnp.int8)
-                cache = dict(cache)
-                cache[name] = cache[name].at[:, phys].set(q)
-                scales = dict(scales)
-                scales[name] = scales[name].at[:, phys].set(s)
-        else:
-            cache = {
-                "k": cache["k"].at[:, phys].set(
-                    k_new.astype(cache["k"].dtype)),
-                "v": cache["v"].at[:, phys].set(
-                    v_new.astype(cache["v"].dtype)),
-            }
+        B = bt.shape[0]
+        phys = jnp.take_along_axis(bt, (kv_len // P)[:, None], axis=1)[:, 0]
+        off = kv_len % P
+        cache, scales = dict(cache), dict(scales)
+        for name, new in (("k", k_new), ("v", v_new)):
+            if self.kv_quant != "int8":
+                cache[name] = _put_slots(
+                    cache[name], new.astype(cache[name].dtype)[:, :, None],
+                    phys, off)
+                continue
+            old = scales[name][:, phys]                     # (L, B)
+            page = (cache[name][:, phys].astype(jnp.float32)
+                    * old[..., None, None, None]).astype(new.dtype)
+            page = page.at[:, jnp.arange(B), off].set(new).astype(
+                jnp.float32)
+            s = jnp.maximum(old, jnp.max(jnp.abs(page), axis=(2, 3, 4))
+                            / 127.0)
+            cache[name] = _put_slots(cache[name], jnp.clip(
+                jnp.round(page / s[..., None, None, None]), -127,
+                127).astype(jnp.int8), phys)
+            scales[name] = _put_slots(scales[name], s, phys)
         return cache, scales
 
-    def _paged_decode(self, params, token, cache, bt, kv_len, key):
+    def _decode_program(self, width: int) -> Callable:
+        """The jitted decode program for a block-table width, with the
+        pool and its scales donated."""
         fused = self.fused_sampling and self.temperature == 0
-        cache_key = (bt.shape[1], self._kv_dtype_key, fused)
+        cache_key = (width, self._kv_dtype_key, fused)
         fn = self._paged_decode_cache.get(cache_key)
         if fn is None:
-            fn = jax.jit(self._paged_decode_fn)
+            fn = jax.jit(self._paged_decode_fn, donate_argnums=(2, 3))
             self._paged_decode_cache[cache_key] = fn
-        return fn(params, token, cache, self.kv_scales, bt, kv_len, key)
+        return fn
+
+    def _paged_decode(self, params, token, cache, bt, kv_len, key):
+        return self._decode_program(bt.shape[1])(params, token, cache,
+                                                 self.kv_scales, bt, kv_len,
+                                                 key)
 
     def _copy_pages(self, copies: List[Tuple[int, int]]) -> None:
         """Apply host-planned copy-on-write page copies on device (scale

@@ -610,6 +610,118 @@ def test_int8_scale_planes_follow_cow_and_migration():
     _drained_pool_is_clean(dst)
 
 
+def _oracle_checked(eng, seen):
+    """Wrap ``eng``'s decode program: every call is checked against the
+    gathered-view oracle (``kernels.ref.paged_decode_step_ref``) on copies
+    of the same inputs, and leaves the pool it was handed consumed by
+    donation.  ``seen`` collects (table width, kv_len % page) per call."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels import ref
+    program = eng._paged_decode
+    oracle = jax.jit(functools.partial(ref.paged_decode_step_ref, eng.model))
+
+    def checked(params, token, cache, bt, kv_len, key):
+        inputs = jax.tree.map(jnp.copy, (cache, eng.kv_scales))
+        sampled, lp, new_cache, new_scales = program(params, token, cache,
+                                                     bt, kv_len, key)
+        assert all(a.is_deleted() for a in jax.tree.leaves(cache)), \
+            "the decode program copied the pool instead of consuming it"
+        logits, want_cache, want_scales = oracle(params, token, *inputs, bt,
+                                                 kv_len)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        want = np.asarray(jnp.argmax(logp, axis=-1))
+        np.testing.assert_array_equal(np.asarray(sampled), want)
+        np.testing.assert_allclose(
+            np.asarray(lp), np.asarray(logp)[np.arange(len(want)), want],
+            atol=1e-4)
+        # every page but the garbage page (inactive slots' writes collide)
+        for got, ref_ in ((new_cache, want_cache), (new_scales, want_scales)):
+            for name in got:
+                np.testing.assert_array_equal(np.asarray(got[name][:, 1:]),
+                                              np.asarray(ref_[name][:, 1:]))
+        act = np.flatnonzero(eng.slots.active)
+        seen.update((int(bt.shape[1]), int(n) % eng.page_size)
+                    for n in np.asarray(kv_len)[act])
+        return sampled, lp, new_cache, new_scales
+
+    eng._paged_decode = checked
+    return eng
+
+
+def _oracle_case(case, eng):
+    """Drive ``eng`` (greedy) through one scenario; returns events."""
+    evs = []
+    if case == "widths":                # table widths 1, 2 and 4 pages
+        eng.submit([BufferEntry(uid=i, prompt=[1 + i] * n)
+                    for i, n in enumerate((3, 9, 14))], version=0)
+        evs += run_to_completion(eng)
+    elif case == "cow":                 # shared prompt, divergent writes
+        eng.submit(group_entries(3, prompt_len=10), version=0)
+        evs += run_to_completion(eng)
+    elif case == "inactive":            # 2 of 4 slots write garbage
+        eng.submit(entries(2, prompt_len=16), version=0)
+        evs += run_to_completion(eng)
+    elif case == "resume":              # interrupt, resume resident pages
+        es = [BufferEntry(uid=i, prompt=[1, 2 + i, 3] * 5)
+              for i in range(4)]
+        eng.submit(es, version=0)
+        gen = {e.uid: [] for e in es}
+        for _ in range(3):
+            for ev in checked_step(eng):
+                gen[ev.uid].append(ev.token)
+        back = eng.interrupt(eng.active_uids()[:2])
+        for _ in range(2):
+            for ev in checked_step(eng):
+                gen[ev.uid].append(ev.token)
+        eng.submit([BufferEntry(uid=u, prompt=list(es[u].prompt),
+                                generated=list(gen[u])) for u in back],
+                   version=0)
+        assert eng.cache_stats()["resumed_without_prefill"] == len(back)
+        evs += run_to_completion(eng)
+    elif case == "migrate":             # export/import after donated steps
+        eng.submit(entries(2, prompt_len=12), version=0)
+        for _ in range(3):
+            evs += checked_step(eng)
+        dst = _oracle_checked(make_slot(capacity=CAPACITY,
+                                        max_gen=MAX_TOTAL - 16,
+                                        kv_quant=eng.kv_quant), set())
+        dst.temperature = 0.0
+        assert dst.import_entry(eng.export_entry(0))
+        eng.discard_entry(0)
+        evs += run_to_completion(dst) + run_to_completion(eng)
+        _drained_pool_is_clean(dst)
+    return evs
+
+
+@pytest.mark.parametrize("quant", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("case", ["widths", "cow", "inactive", "resume",
+                                  "migrate"])
+def test_paged_decode_in_place_matches_gathered_view(case, quant):
+    """The in-place decode program (attention on the pool through the
+    block tables, the new rows written straight into their pages, the
+    pool donated) computes what the gathered-view step computed: the
+    same greedy tokens, logprobs within 1e-4 and the same pages, at every
+    table width, on copy-on-write pages, for inactive slots, at page
+    offsets 0 and P-1, after a resume on resident pages and on both sides
+    of a migration of pages exported after donated steps."""
+    seen = set()
+    eng = _oracle_checked(make_slot(capacity=CAPACITY,
+                                    max_gen=MAX_TOTAL - 16, kv_quant=quant),
+                          seen)
+    eng.temperature = 0.0
+    _oracle_case(case, eng)
+    _drained_pool_is_clean(eng)
+    if case == "widths":
+        assert {w for w, _ in seen} == {1, 2, 4}
+        assert {0, eng.page_size - 1} <= {o for _, o in seen}
+    if case == "cow":
+        assert eng.cache_stats()["cow_copies"] >= 2
+
+
 def test_resident_resume_rate_counts_attempts():
     """resume_attempts counts every try_resume of a previously
     interrupted uid — hits AND misses — so resident_resume_rate is a real

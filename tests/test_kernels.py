@@ -7,12 +7,11 @@ import numpy as np
 import pytest
 
 from repro.kernels.ops import (flash_attention, fused_sample,
-                               paged_decode_attention,
-                               paged_decode_attention_int8,
                                ragged_decode_attention)
+from repro.kernels.ragged_decode_attention import (PAGES_PER_BLOCK,
+                                                   paged_decode_attention)
 from repro.kernels.ref import (KV_INT8_DECODE_ATOL, flash_attention_ref,
                                fused_sample_ref, gather_pages,
-                               paged_decode_attention_int8_ref,
                                paged_decode_attention_ref,
                                quantize_pages_ref, ragged_decode_attention_ref)
 
@@ -67,57 +66,98 @@ def test_ragged_decode_length_one():
                                np.asarray(v[:, 0]), atol=1e-5)
 
 
-@pytest.mark.parametrize("B,H,Kh,D,P,N,nb", [
-    (4, 8, 2, 64, 128, 9, 2),
-    (2, 16, 16, 128, 128, 17, 3),
-    (1, 4, 1, 256, 256, 5, 2),
-])
+def _paged_inputs(key, B, H, Kh, D, P, N, nb, dtype=jnp.float32, L=2,
+                  lens=()):
+    """A layered pool, block tables padded with the garbage page 0 past
+    each slot's pages, kv_len with the first entries set to ``lens``, and
+    the new token's k/v rows.  The rest of kv_len is drawn so that the
+    kv_len + 1 rows attended, the new token's among them, are drawn in
+    [1, nb*P] as the attended lengths of the dense-view tests were."""
+    ks = jax.random.split(key, 5)
+    q = jax.random.normal(ks[0], (B, H, D), dtype)
+    kp = jax.random.normal(ks[1], (L, N, P, Kh, D), dtype)
+    vp = jax.random.normal(ks[2], (L, N, P, Kh, D), dtype)
+    bt = np.array(jax.random.randint(ks[3], (B, nb), 1, N), np.int32)
+    kv_len = np.array(jax.random.randint(ks[4], (B,), 1, nb * P + 1)) - 1
+    kv_len[:len(lens)] = lens
+    for b, n in enumerate(kv_len):
+        bt[b, n // P + 1:] = 0
+    kn, vn = (jax.random.normal(k, (B, Kh, D), dtype)
+              for k in jax.random.split(jax.random.fold_in(key, 1)))
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(kv_len), kn, vn
+
+
+PAGED_CASES = [(4, 8, 2, 64, 128, 9, 2), (2, 16, 16, 128, 128, 17, 3),
+               (1, 4, 1, 256, 256, 5, 2)]
+# at the engine's 16-token pages, with the first slots at the edges
+# (``_edge_lens``); the kernel takes PAGES_PER_BLOCK pages a block
+PAGED_EDGE_CASES = [
+    (5, 16, 8, 128, 16, 40, PAGES_PER_BLOCK),           # one whole block
+    (5, 8, 2, 64, 16, 90, 2 * PAGES_PER_BLOCK + 8),     # three, last padded
+    (4, 14, 2, 64, 16, 30, PAGES_PER_BLOCK + 4),        # two padded; 7:1 GQA
+    (3, 8, 4, 128, 16, 30, 1),                          # one page, one block
+]
+
+
+def _edge_lens(case):
+    """An inactive slot (kv_len 0), the new token on a page's last row
+    (P-1) and first row (P), and a full table (nb*P-1)."""
+    if case not in PAGED_EDGE_CASES:
+        return ()
+    B, P, nb = case[0], case[4], case[6]
+    return [0, P - 1, min(P, nb * P - 1), nb * P - 1][:B]
+
+
+@pytest.mark.parametrize("B,H,Kh,D,P,N,nb", PAGED_CASES + PAGED_EDGE_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention(B, H, Kh, D, P, N, nb, dtype):
-    """Block-table kernel == attention over the gathered dense view."""
-    ks = jax.random.split(KEY, 5)
-    q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (N, P, Kh, D), dtype)
-    vp = jax.random.normal(ks[2], (N, P, Kh, D), dtype)
-    bt = jax.random.randint(ks[3], (B, nb), 0, N)
-    kv_len = jax.random.randint(ks[4], (B,), 1, nb * P + 1)
-    out = paged_decode_attention(q, kp, vp, bt, kv_len)
-    ref = paged_decode_attention_ref(q, kp, vp, bt, kv_len)
+    """Kernel on the layered pool == its jnp reference (gather the
+    layer's pages, write the new row, attend), in every layer, with
+    several pages a block, blocks past kv_len skipped and the table
+    padded with the garbage page."""
+    case = (B, H, Kh, D, P, N, nb)
+    q, kp, vp, bt, kv_len, kn, vn = _paged_inputs(
+        KEY, *case, dtype, lens=_edge_lens(case))
     tol = 2e-6 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=tol,
-                               rtol=tol)
+    for layer in range(kp.shape[0]):
+        out = paged_decode_attention(q, kp, vp, bt, kv_len, layer, kn, vn)
+        ref = paged_decode_attention_ref(q, kp, vp, bt, kv_len, layer, kn,
+                                         vn)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), atol=tol,
+                                   rtol=tol)
 
 
 def test_paged_matches_dense_on_shared_pages():
     """Two slots mapping the SAME physical prefix pages (GRPO sharing)
     attend exactly as two dense slots holding copies of that prefix."""
-    ks = jax.random.split(KEY, 4)
-    H, Kh, D, P, N = 4, 2, 64, 128, 6
+    ks = jax.random.split(KEY, 6)
+    H, Kh, D, P, N, L = 4, 2, 64, 128, 6, 2
     q = jax.random.normal(ks[0], (2, H, D))
-    kp = jax.random.normal(ks[1], (N, P, Kh, D))
-    vp = jax.random.normal(ks[2], (N, P, Kh, D))
+    kp = jax.random.normal(ks[1], (L, N, P, Kh, D))
+    vp = jax.random.normal(ks[2], (L, N, P, Kh, D))
+    kn = jax.random.normal(ks[3], (2, Kh, D))
+    vn = jax.random.normal(ks[4], (2, Kh, D))
     # slot 0: pages [1, 2]; slot 1 shares prefix page 1, then diverges to 3
     bt = jnp.array([[1, 2], [1, 3]], jnp.int32)
     kv_len = jnp.array([200, 170], jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, kv_len)
-    dense_k = gather_pages(kp, bt)
-    dense_v = gather_pages(vp, bt)
-    ref = ragged_decode_attention_ref(q, dense_k, dense_v, kv_len)
+    out = paged_decode_attention(q, kp, vp, bt, kv_len, 1, kn, vn)
+    rows = jnp.arange(2), kv_len
+    dense_k = gather_pages(kp[1], bt).at[rows].set(kn)
+    dense_v = gather_pages(vp[1], bt).at[rows].set(vn)
+    ref = ragged_decode_attention_ref(q, dense_k, dense_v, kv_len + 1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6,
                                rtol=2e-6)
 
 
 def test_paged_decode_attention_softcap():
-    ks = jax.random.split(KEY, 4)
-    B, H, Kh, D, P, N, nb = 2, 4, 2, 64, 128, 7, 2
-    q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (N, P, Kh, D))
-    vp = jax.random.normal(ks[2], (N, P, Kh, D))
-    bt = jax.random.randint(ks[3], (B, nb), 0, N)
-    kv_len = jnp.array([100, 256], jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, kv_len, softcap=20.0)
-    ref = paged_decode_attention_ref(q, kp, vp, bt, kv_len, softcap=20.0)
+    """A partial page and two whole pages, the new token on a third."""
+    q, kp, vp, bt, kv_len, kn, vn = _paged_inputs(KEY, 2, 4, 2, 64, 128, 7,
+                                                  3, lens=[100, 256])
+    out = paged_decode_attention(q, kp, vp, bt, kv_len, 1, kn, vn,
+                                 softcap=20.0)
+    ref = paged_decode_attention_ref(q, kp, vp, bt, kv_len, 1, kn, vn,
+                                     softcap=20.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
 
 
@@ -269,41 +309,41 @@ def test_fused_sample_greedy_identity_on_ties():
 
 # -- int8 KV pages ------------------------------------------------------------
 
-@pytest.mark.parametrize("B,H,Kh,D,P,N,nb", [
-    (4, 8, 2, 64, 128, 9, 2),
-    (2, 16, 16, 128, 128, 17, 3),
-    (1, 4, 1, 256, 256, 5, 2),
-])
+def _quantized(pages):
+    """Per-(layer, page) int8 pages and their (L, N) scales."""
+    L, N = pages.shape[:2]
+    q8, sc = quantize_pages_ref(pages.reshape(L * N, *pages.shape[2:]))
+    return q8.reshape(pages.shape), sc.reshape(L, N)
+
+
+@pytest.mark.parametrize("B,H,Kh,D,P,N,nb",
+                         PAGED_CASES + PAGED_EDGE_CASES[:2])
 def test_paged_decode_int8_matches_dequant_oracle(B, H, Kh, D, P, N, nb):
-    """In-kernel dequant (scalar-prefetched per-page scales) == dequantize
-    the whole pool then run the fp oracle."""
-    ks = jax.random.split(KEY, 5)
-    q = jax.random.normal(ks[0], (B, H, D))
-    kp8, ksc = quantize_pages_ref(jax.random.normal(ks[1], (N, P, Kh, D)))
-    vp8, vsc = quantize_pages_ref(jax.random.normal(ks[2], (N, P, Kh, D)))
-    bt = jax.random.randint(ks[3], (B, nb), 0, N)
-    kv_len = jax.random.randint(ks[4], (B,), 1, nb * P + 1)
-    out = paged_decode_attention_int8(q, kp8, vp8, ksc, vsc, bt, kv_len)
-    ref = paged_decode_attention_int8_ref(q, kp8, vp8, ksc, vsc, bt, kv_len)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6,
-                               rtol=2e-6)
+    """In-kernel dequant (this layer's per-page scales in scalar memory)
+    == dequantize the gathered pages, then the fp reference."""
+    case = (B, H, Kh, D, P, N, nb)
+    q, kp, vp, bt, kv_len, kn, vn = _paged_inputs(KEY, *case,
+                                                  lens=_edge_lens(case))
+    (kp8, ksc), (vp8, vsc) = _quantized(kp), _quantized(vp)
+    for layer in range(kp.shape[0]):
+        out = paged_decode_attention(q, kp8, vp8, bt, kv_len, layer, kn, vn,
+                                     k_scales=ksc, v_scales=vsc)
+        ref = paged_decode_attention_ref(q, kp8, vp8, bt, kv_len, layer, kn,
+                                         vn, k_scales=ksc, v_scales=vsc)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-6, rtol=2e-6)
 
 
 def test_paged_decode_int8_error_within_documented_atol():
     """Quantize fp pages -> int8 decode output stays within the
     documented KV_INT8_DECODE_ATOL of the fp decode on the SAME pages.
     This is the tolerance README promises users of kv_quant="int8"."""
-    ks = jax.random.split(KEY, 5)
-    B, H, Kh, D, P, N, nb = 4, 8, 2, 64, 128, 9, 2
-    q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (N, P, Kh, D))
-    vp = jax.random.normal(ks[2], (N, P, Kh, D))
-    bt = jax.random.randint(ks[3], (B, nb), 0, N)
-    kv_len = jax.random.randint(ks[4], (B,), 1, nb * P + 1)
-    kp8, ksc = quantize_pages_ref(kp)
-    vp8, vsc = quantize_pages_ref(vp)
-    out = paged_decode_attention_int8(q, kp8, vp8, ksc, vsc, bt, kv_len)
-    fp = paged_decode_attention_ref(q, kp, vp, bt, kv_len)
+    q, kp, vp, bt, kv_len, kn, vn = _paged_inputs(KEY, 4, 8, 2, 64, 128, 9,
+                                                  2)
+    (kp8, ksc), (vp8, vsc) = _quantized(kp), _quantized(vp)
+    out = paged_decode_attention(q, kp8, vp8, bt, kv_len, 1, kn, vn,
+                                 k_scales=ksc, v_scales=vsc)
+    fp = paged_decode_attention_ref(q, kp, vp, bt, kv_len, 1, kn, vn)
     err = float(jnp.max(jnp.abs(out - fp)))
     assert err < KV_INT8_DECODE_ATOL, err
 

@@ -30,6 +30,7 @@ QWEN = chip_smoke.qwen3_0_6b()
 H, KH, D = QWEN.num_heads, QWEN.num_kv_heads, QWEN.resolved_head_dim
 SMOKE = chip_smoke.session_config(QWEN)
 SLOTS, MAX_LEN = SMOKE.rollout_batch, SMOKE.max_total_len
+CELL_SLOTS, CELL_LEN = 16, 1024      # the grpo-longtail benchmark cell
 
 
 @pytest.fixture(scope="module")
@@ -77,20 +78,62 @@ def test_ragged_decode_attention_compiles(chip):
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("page", [16, 128])
 def test_paged_decode_attention_compiles(chip, page, int8):
-    nb = MAX_LEN // page
-    n_pages = SLOTS * nb + SLOTS + 1
+    """The decode attention on the grpo-longtail cell's layered pool:
+    28 layers, 16 slots x 1024 tokens, a table of 1024 / page pages."""
+    nb = CELL_LEN // page
+    n_pages = CELL_SLOTS * nb + CELL_SLOTS + 1
     dtype = jnp.int8 if int8 else jnp.bfloat16
-    args = [sds(chip, (SLOTS, H, D), jnp.bfloat16),
-            sds(chip, (n_pages, page, KH, D), dtype),
-            sds(chip, (n_pages, page, KH, D), dtype),
-            sds(chip, (SLOTS, nb), jnp.int32), sds(chip, (SLOTS,), jnp.int32)]
+    pool = (QWEN.num_layers, n_pages, page, KH, D)
+    args = [sds(chip, (CELL_SLOTS, H, D), jnp.bfloat16),
+            sds(chip, pool, dtype), sds(chip, pool, dtype),
+            sds(chip, (CELL_SLOTS, nb), jnp.int32),
+            sds(chip, (CELL_SLOTS,), jnp.int32), sds(chip, (), jnp.int32),
+            sds(chip, (CELL_SLOTS, KH, D), jnp.bfloat16),
+            sds(chip, (CELL_SLOTS, KH, D), jnp.bfloat16)]
     if int8:
-        args += [sds(chip, (n_pages,), jnp.float32)] * 2
+        args += [sds(chip, pool[:2], jnp.float32)] * 2
 
-    def fn(q, kp, vp, bt, n, ks=None, vs=None):
-        return paged_decode_attention(q, kp, vp, bt, n, k_scales=ks,
-                                      v_scales=vs, interpret=False)
+    def fn(q, kp, vp, bt, n, layer, kn, vn, ks=None, vs=None):
+        return paged_decode_attention(q, kp, vp, bt, n, layer, kn, vn,
+                                      k_scales=ks, v_scales=vs,
+                                      interpret=False)
     compile_kernel(fn, *args)
+
+
+def test_paged_attention_serves_a_narrow_head_dim(chip, monkeypatch):
+    """head_dim 64 (Qwen2.5-0.5B widths: 24 layers, 14 / 2 heads, d 896,
+    ffn 4864), the grpo-longtail cell's 16 slots x 1024: the page copies
+    of the kernel do not compile at 64 lanes, so the engine's own donated
+    decode program serves the jnp reference there.  It still works on the
+    pool in place: it aliases the pool and needs a small part of one pool
+    in temporaries (no copy of the pool, which the device lays out with
+    the page axis minor at this width)."""
+    from repro import kernels
+    from repro.configs.base import AttnConfig
+    from repro.models.model import build_model
+    from repro.rollout.engine import SlotEngine
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    cfg = QWEN.replace(name="qwen2.5-0.5b-widths", num_layers=24,
+                       d_model=896, num_heads=14, num_kv_heads=2,
+                       head_dim=64, d_ff=4864,
+                       attn=AttnConfig(rope_theta=1_000_000.0))
+    model = build_model(cfg)
+    params = on_chip(chip, jax.eval_shape(model.init_params,
+                                          jax.random.PRNGKey(0)))
+    eng = SlotEngine(model, lambda: None, capacity=CELL_SLOTS,
+                     max_total_len=CELL_LEN, max_gen_len=CELL_LEN, eos_id=2,
+                     temperature=SMOKE.temperature, num_pages=2)
+    nb = eng._pages_per_seq
+    pool = pool_shapes(model, CELL_SLOTS, CELL_LEN)
+    compiled = eng._decode_program(nb).lower(
+        params, sds(chip, (CELL_SLOTS,), jnp.int32), on_chip(chip, pool),
+        {}, sds(chip, (CELL_SLOTS, nb), jnp.int32),
+        sds(chip, (CELL_SLOTS,), jnp.int32),
+        on_chip(chip, jax.random.PRNGKey(0))).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= nbytes(pool)
+    assert m.temp_size_in_bytes < nbytes(pool) / 20, m.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["causal", "packed"])
@@ -130,11 +173,11 @@ def fits(compiled, resident: int = 0) -> float:
     return need
 
 
-def pool_shapes(model):
-    """The session engine's page pool: one table width of pages per slot,
-    plus copy-on-write headroom and the garbage page."""
+def pool_shapes(model, slots=SLOTS, max_len=MAX_LEN):
+    """An engine's page pool: one table width of pages per slot, plus
+    copy-on-write headroom and the garbage page."""
     page = 16
-    n_pages = SLOTS * (MAX_LEN // page) + SLOTS + 1
+    n_pages = slots * (max_len // page) + slots + 1
     return jax.eval_shape(lambda: model.init_cache(n_pages, page))
 
 
@@ -142,22 +185,36 @@ def nbytes(tree) -> int:
     return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
 
-def test_engine_paged_decode_step_compiles_and_fits(chip, qwen_params):
-    """The session's paged decode step, 8 slots x 512 tokens at full
-    block-table width, compiles for the chip and fits beside the
-    trainer's resident state (params + AdamW moments)."""
+@pytest.mark.parametrize("slots,max_len", [(SLOTS, MAX_LEN),
+                                           (CELL_SLOTS, CELL_LEN)],
+                         ids=["smoke", "cell"])
+def test_engine_paged_decode_step_compiles_and_fits(chip, qwen_params,
+                                                    monkeypatch, slots,
+                                                    max_len):
+    """The engine's own decode program at full block-table width, for the
+    session of chip_smoke.py and for the grpo-longtail cell, compiles
+    with the Pallas attention (the default backend here is the CPU, so
+    the test steers the choice), fits beside the trainer's resident state
+    (params + AdamW moments), aliases the donated pool and needs under
+    0.25 GB of temporaries: no gathered view, no pool copy."""
+    from repro import kernels
     from repro.rollout.engine import SlotEngine
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
     model, params = qwen_params
     # a two-page engine: only the shapes handed to the step matter
-    eng = SlotEngine(model, lambda: None, capacity=SLOTS,
-                     max_total_len=MAX_LEN, max_gen_len=SMOKE.max_gen_len,
+    eng = SlotEngine(model, lambda: None, capacity=slots,
+                     max_total_len=max_len, max_gen_len=max_len,
                      eos_id=2, temperature=SMOKE.temperature, num_pages=2)
     nb = eng._pages_per_seq
-    compiled = jax.jit(eng._paged_decode_fn).lower(
-        params, sds(chip, (SLOTS,), jnp.int32),
-        on_chip(chip, pool_shapes(model)), {},
-        sds(chip, (SLOTS, nb), jnp.int32), sds(chip, (SLOTS,), jnp.int32),
+    pool = pool_shapes(model, slots, max_len)
+    compiled = eng._decode_program(nb).lower(
+        params, sds(chip, (slots,), jnp.int32), on_chip(chip, pool), {},
+        sds(chip, (slots, nb), jnp.int32), sds(chip, (slots,), jnp.int32),
         on_chip(chip, jax.random.PRNGKey(0))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= nbytes(pool)
+    assert m.temp_size_in_bytes < 0.25e9, m.temp_size_in_bytes
     moments = 2 * 4 * QWEN.param_count()          # f32 AdamW m and v
     fits(compiled, resident=moments)
 
