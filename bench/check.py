@@ -71,7 +71,7 @@ def reference_readings(c: Dict, mcfg, mix: Dict, seed: int, rec: Dict,
     batches = rec["batches"] if batches is None else batches
 
     def weights():
-        return make_weights(model, seed, c["initializer_range"])
+        return make_weights(model, c, seed)
 
     p0 = weights()
     names = leaf_names(p0)

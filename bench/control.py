@@ -66,8 +66,8 @@ def readings(c, mcfg, mix, seed, rec, first_step, width,
         from bench.reference import Reference
         from bench.weights import make_weights
         from repro.models.model import build_model
-        v0 = altered(check.served(rec), c["vocab_size"])
-        p0 = make_weights(build_model(mcfg), seed, c["initializer_range"])
+        v0 = altered(check.served(rec), mcfg.vocab_size)
+        p0 = make_weights(build_model(mcfg), c, seed)
         lps = Reference(c).row_logprobs(
             p0, [r["prompt"] + r["generated"] for r in v0], width)
         import numpy as np

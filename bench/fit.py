@@ -3,7 +3,8 @@
 chip, and print what ``memory_analysis`` says each needs:
 
 * the donated update step at ``update_batch`` x ``max_total_len``;
-* the paged decode step at the full block-table width, over the pool;
+* the paged decode step at the full block-table width, over the pool
+  (donated, as the engine jits it);
 * the reference's per-row gradient step at ``max_total_len``, beside the
   float32 weights, moments and gradient sum it keeps.
 
@@ -106,7 +107,9 @@ def main(argv=None) -> int:
                      max_gen_len=M, eos_id=2, temperature=1.0, num_pages=2)
     nb = eng._pages_per_seq
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=chip)
-    report("_paged_decode_fn", jax.jit(eng._paged_decode_fn).lower(
+    # the pool and its scales donated, as the engine jits it
+    report("_paged_decode_fn", jax.jit(
+        eng._paged_decode_fn, donate_argnums=(2, 3)).lower(
         params, i32(S), on_chip(pool), {}, i32(S, nb), i32(S),
         on_chip(jax.random.PRNGKey(0))).compile(), nbytes(opt))
 

@@ -1,56 +1,18 @@
-"""Operations and bytes the work requires, from shapes alone.
+"""Operations the window's work requires, from shapes alone, on the counts
+of the configuration's model family (``bench/families/<family>.py``).
 
-A dense decoder layer multiplies each token by its q, k, v, o and gated
-MLP matrices (2 FLOPs per weight), and attention costs 4 * H * D FLOPs per
-(query, key) pair (scores and the weighted sum).  The LM head is 2 * d * V
-per token.  Training counts forward + backward as 3 forwards, with no
-recomputation (remat is the program's choice, not required work).
+Training counts forward + backward as 3 forwards, with no recomputation
+(remat is the program's choice, not required work).
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Sequence, Tuple
+from types import SimpleNamespace
+from typing import Dict, Iterable, Tuple
 
+from bench import family
 
-def dims(c: Dict) -> Tuple[int, int, int, int, int, int, int]:
-    H = c["num_attention_heads"]
-    D = c.get("head_dim") or c["hidden_size"] // H
-    return (c["num_hidden_layers"], c["hidden_size"], H,
-            c["num_key_value_heads"], D, c["intermediate_size"],
-            c["vocab_size"])
-
-
-def layer_matmul_params(c: Dict) -> int:
-    L, d, H, Kh, D, ff, V = dims(c)
-    return d * H * D + 2 * d * Kh * D + H * D * d + 3 * d * ff
-
-
-def param_count(c: Dict) -> int:
-    L, d, H, Kh, D, ff, V = dims(c)
-    per = layer_matmul_params(c) + 2 * d
-    if c["architecture"]["qk_norm"]:
-        per += 2 * D
-    if c["architecture"]["qkv_bias"]:
-        per += (H + 2 * Kh) * D
-    n = V * d + L * per + d
-    if not c["tie_word_embeddings"]:
-        n += V * d
-    return n
-
-
-def param_bytes(c: Dict) -> int:
-    return 2 * param_count(c)                       # bf16
-
-
-def kv_bytes_per_token(c: Dict) -> int:
-    L, d, H, Kh, D, ff, V = dims(c)
-    return 2 * L * Kh * D * 2                       # K and V, bf16
-
-
-def forward_flops(c: Dict, tokens: float, ctx_sum: float) -> float:
-    """``tokens`` tokens whose attention reads ``ctx_sum`` keys in all."""
-    L, d, H, Kh, D, ff, V = dims(c)
-    return (2.0 * L * layer_matmul_params(c) * tokens
-            + 4.0 * L * H * D * ctx_sum + 2.0 * d * V * tokens)
+# what a family module counts (bench/families/__init__.py)
+FAMILY_COUNTS = ("forward_flops", "decode_step_least")
 
 
 def causal_ctx(n: float) -> float:
@@ -61,6 +23,7 @@ def causal_ctx(n: float) -> float:
 def update_flops(c: Dict, rows: Iterable[Tuple[int, int]]) -> float:
     """Forward + backward over the real (unpadded) tokens of update rows
     given as (prompt length, generated length)."""
+    forward_flops = family.load(c).forward_flops
     tot = 0.0
     for p, g in rows:
         T = p + g
@@ -68,21 +31,22 @@ def update_flops(c: Dict, rows: Iterable[Tuple[int, int]]) -> float:
     return tot
 
 
-def decode_step_least(c: Dict, contexts: Sequence[int],
-                      peak: Dict[str, float]) -> float:
-    """Least seconds of one decode step over active slots with these
-    context lengths: the larger of its FLOPs over peak and its least bytes
-    (the weights once, plus the live K/V of each slot) over bandwidth."""
-    flops = forward_flops(c, len(contexts), sum(contexts))
-    bytes_ = param_bytes(c) + kv_bytes_per_token(c) * sum(contexts)
-    return max(flops / peak["bf16_flops"], bytes_ / peak["hbm_bytes_per_s"])
-
-
 def window_flops(c: Dict, w: Dict) -> float:
     """FLOPs the window required: decode and prefill forwards (a shared
     prefix once) and the updates' forward + backward on real tokens.
     ``w``: decode_tokens, decode_ctx, prefill_tokens, prefill_ctx,
     update_rows."""
+    forward_flops = family.load(c).forward_flops
     return (forward_flops(c, w["decode_tokens"], w["decode_ctx"])
             + forward_flops(c, w["prefill_tokens"], w["prefill_ctx"])
             + update_flops(c, w["update_rows"]))
+
+
+def counts(c: Dict) -> SimpleNamespace:
+    """The counts the metric readers use (``ctx["flops"]``): the family's
+    own, and the update's and the window's built on them; each takes the
+    configuration first."""
+    fam = family.load(c)
+    return SimpleNamespace(**{k: getattr(fam, k) for k in FAMILY_COUNTS},
+                           causal_ctx=causal_ctx, update_flops=update_flops,
+                           window_flops=window_flops)

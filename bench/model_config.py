@@ -1,10 +1,13 @@
 """A configuration file ``bench/configs/<name>.json`` -> the program's
-``ModelConfig``, from the published keys alone."""
+``ModelConfig``, from the published keys alone, by the configuration's
+model family (``bench/family.py``)."""
 from __future__ import annotations
 
 import json
 import os
 from typing import Dict, Tuple
+
+from bench import family
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -14,31 +17,6 @@ def load_config_file(name: str) -> Dict:
         return json.load(f)
 
 
-def to_model_config(name: str, c: Dict):
-    import jax.numpy as jnp
-    from repro.configs.base import AttnConfig, ModelConfig
-    arch, prog = c["architecture"], c["program"]
-    assert c["hidden_act"] == "silu" and not c.get("use_sliding_window"), c
-    assert c.get("rope_scaling") is None, c
-    return ModelConfig(
-        name=name, family="dense",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        head_dim=c.get("head_dim")
-        or c["hidden_size"] // c["num_attention_heads"],
-        attn=AttnConfig(qk_norm=arch["qk_norm"], qkv_bias=arch["qkv_bias"],
-                        rope_theta=float(c["rope_theta"])),
-        mlp_act="silu", gated_mlp=True, norm_type="rmsnorm",
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=c["tie_word_embeddings"],
-        remat=prog["remat"],
-        param_dtype=jnp.dtype(prog["param_dtype"]),
-        compute_dtype=jnp.dtype(prog["compute_dtype"]),
-        source=c["source"])
-
-
 def load_model_config(name: str) -> Tuple[Dict, object]:
     c = load_config_file(name)
-    return c, to_model_config(name, c)
+    return c, family.load(c).model_config(name, c)

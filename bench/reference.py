@@ -1,14 +1,16 @@
-"""Plain reference: the published Qwen2/Qwen3 decoder in float32 jax.numpy,
-the GRPO advantage, the clipped policy-gradient loss (paper Eq. 1 with
-DAPO's clip-higher) and AdamW, written from their descriptions and
-importing nothing of the program.
+"""Plain reference: a configuration's decoder forward in float32
+jax.numpy, from its model family (``bench/families/<family>.py``), with
+what no family changes: token logprobs, the GRPO advantage, the clipped
+policy-gradient loss (paper Eq. 1 with DAPO's clip-higher) and AdamW,
+written from their descriptions and importing nothing of the program.
 
-It reads the weight tree of :mod:`bench.weights` by leaf name (``embed``,
-``layers/attn/wq`` ...), with the program's orientation of each matrix.
-``mode="f32"`` multiplies at full float32 precision; ``mode="fp8"`` rounds
-both operands of every matrix product to float8 (e4m3) first (gradients
-pass the rounding straight through) and is the control: the reference
-computed one precision below the bfloat16 that the configurations state.
+The forward reads the weight tree of :mod:`bench.weights` by leaf name
+(``embed``, ``layers/attn/wq`` ...), with the program's orientation of each
+matrix, and multiplies through :func:`mm`.  ``mode="f32"`` multiplies at
+full float32 precision; ``mode="fp8"`` rounds both operands of every matrix
+product to float8 (e4m3) first (gradients pass the rounding straight
+through) and is the control: the reference computed one precision below
+the bfloat16 that the configurations state.
 
 Rows are processed one at a time, and layers under ``jax.checkpoint``, so
 that a 1024-token row of a 0.6B model fits beside float32 AdamW state.
@@ -23,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import family
 from bench.weights import leaf_norms
 
 F32 = jnp.float32
@@ -38,26 +41,13 @@ def _fp8(x):
         x.astype(jnp.float8_e4m3fn).astype(F32) - x)
 
 
-def _mm(eq: str, a, b, mode: str):
+def mm(eq: str, a, b, mode: str):
+    """``jnp.einsum`` at full float32 precision; under the control
+    (``mode="fp8"``) both operands are rounded to e4m3 first.  Every
+    family's forward multiplies through here."""
     if mode == "fp8":
         a, b = _fp8(a), _fp8(b)
     return jnp.einsum(eq, a.astype(F32), b.astype(F32), precision=HIGHEST)
-
-
-def _rms(x, scale, eps):
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
-        * scale.astype(F32)
-
-
-def _rope(x, positions, theta):
-    """Rotate-half RoPE (HF Qwen2/Qwen3): pairs (i, i + D/2)."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
-    ang = positions.astype(F32)[:, None] * inv[None]          # (S, D/2)
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
 class Reference:
@@ -65,53 +55,11 @@ class Reference:
 
     def __init__(self, c: Dict, mode: str = "f32"):
         assert mode in ("f32", "fp8"), mode
-        self.mode = mode
-        self.H = c["num_attention_heads"]
-        self.Kh = c["num_key_value_heads"]
-        self.D = c.get("head_dim") or c["hidden_size"] // self.H
-        self.eps = float(c["rms_norm_eps"])
-        self.theta = float(c["rope_theta"])
-        self.qk_norm = c["architecture"]["qk_norm"]
-        self.tied = c["tie_word_embeddings"]
-
-    # -- forward -------------------------------------------------------------
-
-    def _layer(self, x, p):
-        mode, eps = self.mode, self.eps
-        S = x.shape[1]
-        pos = jnp.arange(S)
-        a = p["attn"]
-        h = _rms(x, p["ln1"]["scale"], eps)
-        q = _mm("bsd,dhk->bshk", h, a["wq"], mode)
-        k = _mm("bsd,dhk->bshk", h, a["wk"], mode)
-        v = _mm("bsd,dhk->bshk", h, a["wv"], mode)
-        if "bq" in a:
-            q, k, v = (q + a["bq"].astype(F32), k + a["bk"].astype(F32),
-                       v + a["bv"].astype(F32))
-        if self.qk_norm:
-            q, k = _rms(q, a["q_norm"], eps), _rms(k, a["k_norm"], eps)
-        q, k = _rope(q, pos, self.theta), _rope(k, pos, self.theta)
-        g = self.H // self.Kh                 # query head i reads kv head i//g
-        k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
-        s = _mm("bqhd,bkhd->bhqk", q, k, mode) / math.sqrt(self.D)
-        causal = jnp.tril(jnp.ones((S, S), bool))
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        o = _mm("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, mode)
-        x = x + _mm("bshk,hkd->bsd", o, a["wo"], mode)
-        h = _rms(x, p["ln2"]["scale"], eps)
-        m = p["mlp"]
-        u = jax.nn.silu(_mm("bsd,df->bsf", h, m["w_in"], mode)) \
-            * _mm("bsd,df->bsf", h, m["w_gate"], mode)
-        return x + _mm("bsf,fd->bsd", u, m["w_out"], mode), None
+        self.forward = family.load(c).Forward(c, mode)
 
     def logits(self, params, tokens):
         """tokens (B, S) -> float32 logits (B, S, V)."""
-        x = jnp.take(params["embed"], tokens, axis=0).astype(F32)
-        x, _ = jax.lax.scan(jax.checkpoint(self._layer), x, params["layers"])
-        x = _rms(x, params["final_norm"]["scale"], self.eps)
-        if self.tied:
-            return _mm("bsd,vd->bsv", x, params["embed"], self.mode)
-        return _mm("bsd,dv->bsv", x, params["lm_head"], self.mode)
+        return self.forward.logits(params, tokens)
 
     def token_logprobs(self, params, tokens):
         """(B, S): entry t is log p(tokens[t] | tokens[:t]); entry 0 is 0."""

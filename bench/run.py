@@ -128,6 +128,14 @@ class WarmMeta:
     prompt_id: int
 
 
+def prefill_widths(mix: Dict) -> List[int]:
+    """The prefill width buckets of the mix's fresh prompts."""
+    from repro.rollout.engine import next_pow2
+    lo, hi = mix["prompt_len"]
+    return sorted({min(next_pow2(n - 1), mix["max_total_len"])
+                   for n in range(lo, hi + 1)})
+
+
 # ---------------------------------------------------------------------------
 # the session under test
 # ---------------------------------------------------------------------------
@@ -157,7 +165,7 @@ class Cell:
         vocab = self.traffic.vocab
         from repro.train.optimizer import AdamWConfig
         self.trainer = RLTrainer(
-            self.model, make_weights(self.model, seed, c["initializer_range"]),
+            self.model, make_weights(self.model, c, seed),
             self.traffic.reward,
             loss_cfg=LossConfig(clip_eps_low=mix["clip_eps"][0],
                                 clip_eps_high=mix["clip_eps"][1]),
@@ -249,8 +257,7 @@ class Cell:
                 leaf_norms(st.opt_state.m)) / (1 - b1)
         if n + 1 == self.mix["check_updates"]:
             import numpy as np
-            p0 = make_weights(self.model, self.seed,
-                              self.c["initializer_range"])
+            p0 = make_weights(self.model, self.c, self.seed)
             self.check_rec["dp_norms"] = np.asarray(
                 diff_norms(st.params, p0))
             del p0
@@ -324,9 +331,7 @@ class Cell:
 
         # prefill buckets: fresh prompts (the only prefills the mixes make:
         # resumes find their pages resident) at every batch bucket
-        short = sorted({min(next_pow2(n - 1), M)
-                        for n in range(mix["prompt_len"][0],
-                                       mix["prompt_len"][1] + 1)})
+        short = prefill_widths(mix)
         batches = sorted({min(next_pow2(k), cap) for k in range(1, cap + 1)})
         shapes = [(W, kb) for W in short for kb in batches]
         for W, kb in shapes:
@@ -384,7 +389,7 @@ class Cell:
         eng, mix = self.engine, self.mix
         M, cap, P = mix["max_total_len"], mix["slots"], eng.page_size
         pages = -(-M // P)
-        V = self.c["vocab_size"]
+        V = self.mcfg.vocab_size
         n_steps = (4, 2, 4)
         widths = sorted({min(next_pow2(b), pages)
                          for b in range(1, pages + 1)})
@@ -552,7 +557,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                    for k, v in sess.spans.count.items()},
         "capacity": mix["slots"],
         "config": c, "mix": mix, "peak": peak,
-        "flops": F, "trace": tracer.summary if tracer else None,
+        "flops": F.counts(c), "trace": tracer.summary if tracer else None,
     }
     ctx["window_flops"] = F.window_flops(c, work)
     attempted = sum(1 for t in sess.adapter.first_admit.values()

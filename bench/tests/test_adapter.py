@@ -15,7 +15,7 @@ def test_adapter_ends_each_trajectory_at_its_drawn_length():
     cell = tiny_cell()
     c, mcfg, mix = cell["config"], cell["mcfg"], cell["mix"]
     model = build_model(mcfg)
-    params = make_weights(model, 5, c["initializer_range"])
+    params = make_weights(model, c, 5)
     # eos -1 is never sampled, so only the drawn lengths end trajectories
     eng = SlotEngine(model, lambda: params, capacity=mix["slots"],
                      max_total_len=mix["max_total_len"],

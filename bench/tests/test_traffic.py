@@ -1,3 +1,9 @@
+import hashlib
+import json
+
+import pytest
+
+from bench.run import prefill_widths
 from bench.traffic import Traffic, load_mix, strata_lengths
 
 
@@ -81,3 +87,35 @@ def test_each_rollout_batch_gets_the_same_lengths_for_every_seed():
         order = sorted(range(len(ps)), key=lambda i: (len(ps[i]), i))
         firsts.append(sorted(min(ls[i], 950) for i in order[:16]))
     assert firsts[0] == firsts[1] == firsts[2]
+
+
+def digest(seed, n=60):
+    t = Traffic(load_mix("grpo-longtail"), seed)
+    h = hashlib.sha256()
+    for _ in range(n):
+        p, _, lens = t.next_group()
+        h.update(json.dumps([p, lens]).encode())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("seed", [9800000097, 9600000071])
+def test_seeds_with_the_longest_prompts_draw_their_groups(seed):
+    # each draws a prompt of 39 or more tokens within 16 groups: the mix's
+    # prompt_len is what the logic generator's construction can give,
+    # 3-5 statements of 6 or 8 tokens between <bos> and <ans>: 20-42
+    t = Traffic(load_mix("grpo-longtail"), seed)
+    longest = max(len(p) for _ in range(60) for p in t.next_group()[0])
+    assert 39 <= longest <= 42
+
+
+def test_a_seed_draws_what_it_drew_under_the_narrower_range():
+    # the digest of these 60 groups under prompt_len [20, 38]
+    assert digest(7100000001) == \
+        "2b6932114e749639bed5a248a11734944dfa93b9fdf0fd3a4cb679d20bd92745"
+
+
+@pytest.mark.parametrize("mix", ["grpo-longtail", "grpo-longtail-wide",
+                                 "short-answer"])
+def test_prefill_widths_of_the_prompt_range(mix):
+    # as under prompt_len [20, 38]: warm-up compiles the same prefills
+    assert prefill_widths(load_mix(mix)) == [32, 64]

@@ -1,7 +1,8 @@
 """A tiny stand-in for a cell, for runs of the harness on the CPU: the
-cell's configuration and mix with small widths, few slots and short
-outputs."""
-from bench.model_config import load_config_file, to_model_config
+cell's configuration at its family's small size (``TINY``), and its mix
+with few slots and short outputs."""
+from bench import family
+from bench.model_config import load_config_file
 from bench.traffic import load_mix
 
 # Limits of ``correct`` for the tiny stand-in, set like the cell's from its
@@ -15,12 +16,11 @@ TINY_LIMITS = {"lp_gap": 0.009, "loss_gap": 0.0008, "grad_gap": 0.006,
 
 def tiny_cell(config="qwen3-0.6b", mix="grpo-longtail", limits=TINY_LIMITS,
               **kw):
-    c = dict(load_config_file(config), num_hidden_layers=2, hidden_size=64,
-             intermediate_size=128, num_attention_heads=4,
-             num_key_value_heads=2, head_dim=16, vocab_size=256)
+    c = load_config_file(config)
+    c = dict(c, **family.load(c).TINY)
     m = dict(load_mix(mix), slots=4, max_total_len=128,
              output_len={"kind": "lognormal_strata", "median": 20,
                          "sigma": 0.9})
     m.update(kw)
-    return {"config": c, "mcfg": to_model_config(config, c), "mix": m,
-            "limits": limits}
+    return {"config": c, "mcfg": family.load(c).model_config(config, c),
+            "mix": m, "limits": limits}

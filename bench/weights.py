@@ -14,6 +14,8 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from bench import family
+
 
 def seed_words(seed: int) -> Tuple[int, int]:
     """Low and high 32-bit words: PRNGKey alone drops bits above 32."""
@@ -24,20 +26,10 @@ def leaf_name(path) -> str:
     return "/".join(str(getattr(k, "key", k)) for k in path)
 
 
-def _init_std(name: str, init_range: float) -> Tuple[float, float]:
-    """(mean, std) of a leaf.  Norm scales sit near 1 and biases are small
-    but not zero, so a path that drops either shows in the comparison."""
-    last = name.rsplit("/", 1)[-1]
-    if last in ("scale", "q_norm", "k_norm"):
-        return 1.0, 0.1
-    if last in ("bq", "bk", "bv"):
-        return 0.0, 0.1
-    return 0.0, init_range
-
-
 @functools.lru_cache(maxsize=4)
-def weight_fn(model, init_range: float):
-    """Jitted (lo, hi) -> params for this model."""
+def weight_fn(model, init_std, init_range: float):
+    """Jitted (lo, hi) -> params for this model, each leaf's (mean, std)
+    from its family's ``init_std``."""
     template = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(template)
 
@@ -46,7 +38,7 @@ def weight_fn(model, init_range: float):
         key = jax.random.fold_in(jax.random.PRNGKey(lo), hi)
         out = []
         for i, (path, sd) in enumerate(leaves):
-            mean, std = _init_std(leaf_name(path), init_range)
+            mean, std = init_std(leaf_name(path), init_range)
             z = jax.random.normal(jax.random.fold_in(key, i), sd.shape,
                                   jnp.float32)
             out.append((mean + std * z).astype(sd.dtype))
@@ -55,9 +47,11 @@ def weight_fn(model, init_range: float):
     return build
 
 
-def make_weights(model, seed: int, init_range: float) -> Dict:
+def make_weights(model, c: Dict, seed: int) -> Dict:
+    """The weights of ``model``, built from configuration ``c``."""
     lo, hi = seed_words(seed)
-    return weight_fn(model, init_range)(jnp.uint32(lo), jnp.uint32(hi))
+    build = weight_fn(model, family.load(c).init_std, c["initializer_range"])
+    return build(jnp.uint32(lo), jnp.uint32(hi))
 
 
 def leaf_names(tree) -> List[str]:
